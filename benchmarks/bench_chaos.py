@@ -36,7 +36,8 @@ from benchmarks.common import RESULTS, emit, reference_library
 from repro.api import (DeviceInventory, FleetTelemetryMux, MinosSession,
                        ReferenceLibrary, StragglerMonitor, TPUPowerModel,
                        VariabilityModel, count_classifier_calls,
-                       fleet_job_mix, micro_gemm, micro_idle_burst,
+                       enable_compilation_cache, fleet_job_mix, micro_gemm,
+                       micro_idle_burst,
                        micro_spmv_compute, micro_spmv_memory, micro_stencil,
                        simulate, stream_profile_workload, stream_telemetry)
 
@@ -252,6 +253,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="micro-zoo configuration for CI")
     args = ap.parse_args()
+    enable_compilation_cache()
     print(json.dumps(run(smoke=args.smoke), indent=1))
 
 

@@ -36,7 +36,8 @@ import time
 
 from benchmarks.common import RESULTS, emit
 from repro.api import (MinosSession, ReferenceLibrary, TPUPowerModel,
-                       count_classifier_calls, micro_gemm, micro_idle_burst,
+                       count_classifier_calls, enable_compilation_cache,
+                       micro_gemm, micro_idle_burst,
                        micro_spmv_compute, micro_spmv_memory, micro_stencil,
                        novel_streams, resolve_objective,
                        stream_profile_workload, stream_profiler,
@@ -240,6 +241,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="2 novel families, shorter profiles (CI)")
     args = ap.parse_args()
+    enable_compilation_cache()
     out = run(smoke=args.smoke)
     print(json.dumps(out, indent=1))
 

@@ -30,7 +30,8 @@ import numpy as np
 
 from benchmarks.common import RESULTS, emit, reference_library
 from repro.api import (DeviceInventory, MinosSession, ReferenceLibrary,
-                       TPUPowerModel, VariabilityModel, fleet_job_mix,
+                       TPUPowerModel, VariabilityModel,
+                       enable_compilation_cache, fleet_job_mix,
                        micro_gemm, micro_idle_burst, micro_spmv_compute,
                        micro_spmv_memory, micro_stencil, simulate,
                        stream_profile_workload)
@@ -158,6 +159,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="micro-zoo configuration for CI")
     args = ap.parse_args()
+    enable_compilation_cache()
     print(json.dumps(run(smoke=args.smoke), indent=1))
 
 

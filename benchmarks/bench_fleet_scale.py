@@ -53,9 +53,10 @@ import numpy as np
 from benchmarks.common import RESULTS, emit, reference_library
 from repro.api import (DeviceInventory, ReferenceLibrary, TPUPowerModel,
                        VariabilityModel, count_classifier_calls,
-                       fleet_job_mix, micro_gemm, micro_idle_burst,
-                       micro_spmv_compute, micro_spmv_memory, micro_stencil,
-                       simulate, stream_profile_workload, stream_telemetry)
+                       enable_compilation_cache, fleet_job_mix, micro_gemm,
+                       micro_idle_burst, micro_spmv_compute,
+                       micro_spmv_memory, micro_stencil, simulate,
+                       stream_profile_workload, stream_telemetry)
 from repro.fleet import FleetCapController, FleetTelemetryMux
 
 SUSTAIN_WINDOW = 50              # samples for the sustained rolling mean
@@ -301,6 +302,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="2k-job micro-zoo configuration for CI")
     args = ap.parse_args()
+    enable_compilation_cache()
     print(json.dumps(run(smoke=args.smoke), indent=1))
 
 

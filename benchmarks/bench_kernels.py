@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import RESULTS, emit
+from repro.api import enable_compilation_cache
 from repro.kernels import flash_attention, ref, rmsnorm, spike_hist, ssm_scan
 
 
@@ -87,4 +88,5 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     print(run()["worst"])

@@ -40,7 +40,8 @@ import numpy as np
 from benchmarks.common import RESULTS, emit
 from repro.api import (DeviceInventory, FleetTelemetryMux, MinosSession,
                        ReferenceLibrary, TPUPowerModel, VariabilityModel,
-                       count_classifier_calls, micro_gemm, micro_idle_burst,
+                       count_classifier_calls, enable_compilation_cache,
+                       micro_gemm, micro_idle_burst,
                        micro_spmv_compute, micro_spmv_memory, micro_stencil,
                        simulate, stream_profile_workload, stream_telemetry)
 
@@ -126,6 +127,8 @@ def run(smoke: bool = False) -> dict:
     store = os.path.join(tempfile.mkdtemp(prefix="minos-recovery-"), "store")
 
     # -- crash: the child takes SIGKILL mid-stream -----------------------
+    # the child's engine needs the chip, which belongs to one process: it
+    # runs before this process touches a JAX backend
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", store]
         + (["--smoke"] if smoke else []))
@@ -236,6 +239,7 @@ def main() -> None:
     ap.add_argument("--child", metavar="STORE",
                     help=argparse.SUPPRESS)   # internal crash-target mode
     args = ap.parse_args()
+    enable_compilation_cache()
     if args.child:
         child(args.child, smoke=args.smoke)
         return

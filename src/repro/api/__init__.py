@@ -34,6 +34,7 @@ from repro.api.registry import (ACTUATORS, OBJECTIVES, QUANTILES,
                                 register_objective, register_quantile)
 from repro.api.results import (SessionReport, from_dict, from_json, to_dict,
                                to_json)
+from repro.api.compile_cache import enable_compilation_cache
 from repro.api.session import JobHandle, MinosSession
 
 # the engine underneath, re-exported so facade users need one import root
@@ -77,7 +78,7 @@ from repro.telemetry.workloads import (fleet_job_mix, holdout_streams,
 
 __all__ = [
     # facade
-    "MinosSession", "JobHandle", "SessionReport",
+    "MinosSession", "JobHandle", "SessionReport", "enable_compilation_cache",
     # registries / plugin policies
     "Registry", "OBJECTIVES", "ACTUATORS", "QUANTILES",
     "register_objective", "register_actuator", "register_quantile",

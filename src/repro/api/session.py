@@ -522,6 +522,14 @@ class MinosSession:
         return session
 
     @property
+    def engine(self):
+        """The fleet's shared ``BatchProfileEngine``: ``engine.warmup(n)``
+        compiles its device histogram programs for up to ``n`` concurrent
+        jobs before traffic arrives, and ``device_calls``/``device_shapes``
+        count its device work."""
+        return self._fleet.engine
+
+    @property
     def store(self) -> SessionStore | None:
         """The attached durable session store (``None`` = not durable)."""
         return self._store

@@ -56,11 +56,8 @@ def ema_filter(power: np.ndarray, alpha: float = 0.5,
 
 
 def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:      # pragma: no cover - jax is always present here
-        return False
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def trim_idle(power: np.ndarray, busy: np.ndarray) -> np.ndarray:

@@ -482,8 +482,10 @@ class FleetCapController:
         re-packs the fleet); ``None`` otherwise.
 
         Telemetry from a failed device (in flight when the failure landed)
-        is discarded, as is telemetry for a job that has left the fleet —
-        the wire keeps no promises under churn.  With a straggler adapter
+        is discarded, as is telemetry for a job that has left the fleet or
+        that awaits its re-run after a mid-profile migration (the chunk
+        belongs to the run that died; ``restart_profile`` starts the new
+        one) — the wire keeps no promises under churn.  With a straggler adapter
         attached, every chunk also feeds the per-device cadence monitor and
         flagged devices are degraded-and-drained automatically."""
         if self.straggler_adapter is not None:
@@ -494,8 +496,8 @@ class FleetCapController:
             self._dropped += 1
             return None
         job = self.jobs.get(fchunk.job_id)
-        if job is None:                    # retired/stranded mid-stream
-            self._dropped += 1
+        if job is None or job.needs_reprofile:
+            self._dropped += 1             # retired, or a dead run's chunk
             return None
         return self.ingest_chunk(fchunk.job_id, fchunk.chunk)
 
@@ -564,13 +566,12 @@ class FleetCapController:
                     self._dropped += 1
                     continue
                 job = jobs_get(fc.job_id)
-                if job is None:            # retired/stranded mid-stream
-                    self._dropped += 1
+                if job is None or job.needs_reprofile:
+                    self._dropped += 1     # retired, or a dead run's chunk
                     continue
                 eligible = (eng is not None
                             and fc.job_id not in seen
                             and getattr(job.builder, "engine", None) is eng
-                            and not job.needs_reprofile
                             and (job.decision is None
                                  or job.profile_to_completion))
                 seen.add(fc.job_id)

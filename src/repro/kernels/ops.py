@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from repro.kernels.ema_scan import ema_scan_pallas
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rmsnorm import rmsnorm_pallas
-from repro.kernels.spike_hist import spike_hist_pallas
+from repro.kernels.spike_hist import (spike_hist_packed_pallas,
+                                      spike_hist_pallas)
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
 
@@ -56,6 +57,16 @@ def spike_hist(power: jax.Array, tdp: float | jax.Array, n_bins: int = 15,
     counts = spike_hist_pallas(rel, n_bins, lo=lo, hi=hi, interpret=interpret)
     total = jnp.sum(counts)
     return jnp.where(total > 0, counts / total, counts)
+
+
+@partial(jax.jit, static_argnames=("fields", "interpret"))
+def spike_hist_packed(packed: jax.Array, fields,
+                      interpret: bool | None = None) -> jax.Array:
+    """(rows, samples) packed int32 bin indices -> (rows, 128) int32 lane
+    counts (layout: ``spike_hist.pack_fields``) — the fleet engine's one
+    device call per tick."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return spike_hist_packed_pallas(packed, fields, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("alpha", "interpret"))

@@ -1,11 +1,13 @@
-"""Power-spike histogram as a Pallas TPU kernel — Minos's own telemetry
-binning (paper §4.1.1) as an on-device streaming op.
+"""Power-spike histograms as Pallas TPU kernels — Minos's own telemetry
+binning (paper §4.1.1) as on-device streaming ops.
 
-A fleet-scale deployment bins millions of 1 kHz power samples per chip per
-day; doing it on-device (VPU compare + reduce per bin over VMEM-resident
-sample tiles, accumulated across the sequential grid) avoids shipping raw
-traces to the host.  The op is bandwidth-bound streaming: one pass over the
-samples, one (8, 128) accumulator tile resident in VMEM.
+``spike_hist_pallas`` bins one trace of float32 relative magnitudes.  The
+fleet engine's kernel, ``spike_hist_packed_pallas``, only *counts*: the host
+computes every spike sample's bin index in float64 (the exact expression of
+the NumPy reference), packs the indices of all tracked bin sizes into one
+int32 per sample (``pack_fields``), and the device accumulates integer
+counts per row.  Binning float32 values on the device would move samples
+that sit within an ulp of a bin edge, so the engine never does that.
 """
 from __future__ import annotations
 
@@ -16,6 +18,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _OUT_COLS = 128   # one padded output tile; n_bins <= 128
+_SUB = 8          # rows per one-hot slab: bounds the (rows, 128, 128) temp
+
+
+def _lane_counts(idx: jax.Array) -> jax.Array:
+    """(rows, 128) int32 lane ids (-1 = none) -> (rows, 128) int32 counts:
+    a one-hot over the 128 lanes, reduced across each row's samples."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _OUT_COLS), 2)
+    return jnp.sum((idx[:, :, None] == lanes).astype(jnp.int32), axis=1)
 
 
 def _hist_kernel(r_ref, o_ref, *, n_bins: int, lo: float, hi: float):
@@ -30,11 +40,11 @@ def _hist_kernel(r_ref, o_ref, *, n_bins: int, lo: float, hi: float):
     # bin index per sample; out-of-range -> -1 (not counted)
     idx = jnp.floor((r - lo) / width).astype(jnp.int32)
     idx = jnp.where(r >= lo, jnp.minimum(idx, n_bins - 1), -1)
-    # accumulate counts: compare against the 128 bin ids held in the lanes
-    bins = jax.lax.broadcasted_iota(jnp.int32, (1, _OUT_COLS), 1)
-    counts = jnp.sum(
-        (idx.reshape(-1, 1) == bins).astype(jnp.float32), axis=0, keepdims=True)
-    o_ref[0:1, :] += counts
+    sub = min(_SUB, idx.shape[0])
+    acc = jnp.zeros((1, _OUT_COLS), jnp.int32)
+    for s in range(0, idx.shape[0], sub):
+        acc += jnp.sum(_lane_counts(idx[s:s + sub]), axis=0, keepdims=True)
+    o_ref[0:1, :] += acc.astype(jnp.float32)
 
 
 def spike_hist_pallas(rel_power: jax.Array, n_bins: int, lo: float = 0.5,
@@ -72,60 +82,65 @@ def spike_hist_pallas(rel_power: jax.Array, n_bins: int, lo: float = 0.5,
     return out[0, :n_bins]
 
 
-def _batch_hist_kernel(r_ref, o_ref, *, n_bins: int, lo: float,
-                       bin_width: float):
+def pack_fields(n_bins) -> tuple[tuple[int, int, int], ...]:
+    """The packed-index layout for histograms of ``n_bins[b]`` bins each:
+    one ``(shift, mask, lane_offset)`` per histogram.  Bin index ``i`` of
+    histogram ``b`` sits in bits ``shift:shift + width`` of the packed int32
+    and is counted in output lane ``lane_offset + i``.  Raises
+    ``ValueError`` when the histograms need more than 31 bits or 128
+    lanes."""
+    fields, shift, offset = [], 0, 0
+    for n in n_bins:
+        width = max(1, (int(n) - 1).bit_length())
+        fields.append((shift, (1 << width) - 1, offset))
+        shift += width
+        offset += int(n)
+    if shift > 31 or offset > _OUT_COLS:
+        raise ValueError(
+            f"histograms of {list(n_bins)} bins need {shift} index bits and "
+            f"{offset} lanes; one packed int32 holds 31 bits and 128 lanes")
+    return tuple(fields)
+
+
+def _packed_count_kernel(p_ref, o_ref, *, fields):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    r = r_ref[...].astype(jnp.float32)            # (block_jobs, 128)
-    idx = jnp.floor((r - lo) / bin_width).astype(jnp.int32)
-    idx = jnp.where(r >= lo, jnp.minimum(idx, n_bins - 1), -1)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _OUT_COLS), 2)
-    # one-hot over the lane-held bin ids, reduced across this sample tile
-    counts = jnp.sum((idx[:, :, None] == bins).astype(jnp.float32), axis=1)
-    o_ref[...] += counts                           # (block_jobs, _OUT_COLS)
+    p = p_ref[...]                                 # (block_rows, 128) int32
+    acc = jnp.zeros(o_ref.shape, jnp.int32)
+    for shift, mask, offset in fields:
+        lane = jnp.where(p >= 0, ((p >> shift) & mask) + offset, -1)
+        acc += _lane_counts(lane)
+    o_ref[...] += acc
 
 
-def spike_hist_batch_pallas(rel_power: jax.Array, n_bins: int,
-                            lo: float = 0.5, hi: float = 2.0,
-                            bin_width: float | None = None,
-                            block_jobs: int = 8,
-                            interpret: bool | None = None) -> jax.Array:
-    """Batched fleet variant: (jobs, samples) f32 -> (jobs, n_bins) counts.
+def spike_hist_packed_pallas(packed: jax.Array, fields, block_rows: int = 8,
+                             interpret: bool | None = None) -> jax.Array:
+    """(rows, samples) int32 packed bin indices -> (rows, 128) int32 counts.
 
-    One kernel launch bins every live job's newly committed samples at once —
-    the TPU half of ``pipeline.batch.BatchProfileEngine``'s histogram
-    scatter.  Rows are jobs; sample padding uses -inf (never counted), so
-    ragged per-job sample counts are handled by masking before the call.
-    ``bin_width`` defaults to ``(hi - lo) / n_bins`` but callers that track
-    histograms keyed by an exact bin size should pass it explicitly —
-    ``(hi - lo) / n_bins`` re-derived in float can differ in the last ulp
-    from the originating bin size (e.g. 0.15).  ``interpret=None``
-    autodetects like ``spike_hist_pallas``.
-    """
-    assert n_bins <= _OUT_COLS
+    Each non-negative entry is one spike sample whose per-histogram bin
+    indices were packed on the host by the ``fields`` layout
+    (``pack_fields``); -1 entries are never counted.  ``rows`` must be a
+    multiple of ``block_rows`` and ``samples`` a multiple of 128 — the
+    engine pads to fixed buckets so a drive compiles a bounded set of
+    programs.  Counts are exact int32.  ``interpret=None`` autodetects like
+    ``spike_hist_pallas``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if bin_width is None:
-        bin_width = (hi - lo) / n_bins
-    jobs, n = rel_power.shape
+    rows, n = packed.shape
     cols = 128
-    jb = -(-jobs // block_jobs) * block_jobs
-    sb = -(-n // cols) * cols
-    r = jnp.pad(rel_power.astype(jnp.float32),
-                ((0, jb - jobs), (0, sb - n)), constant_values=-jnp.inf)
-    grid = (jb // block_jobs, sb // cols)
-    kernel = functools.partial(_batch_hist_kernel, n_bins=n_bins, lo=lo,
-                               bin_width=bin_width)
-    out = pl.pallas_call(
+    if rows % block_rows or n % cols:
+        raise ValueError(f"packed shape {packed.shape} must tile by "
+                         f"({block_rows}, {cols})")
+    kernel = functools.partial(_packed_count_kernel, fields=tuple(fields))
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_jobs, cols), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((block_jobs, _OUT_COLS), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((jb, _OUT_COLS), jnp.float32),
+        grid=(rows // block_rows, n // cols),
+        in_specs=[pl.BlockSpec((block_rows, cols), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((block_rows, _OUT_COLS), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, _OUT_COLS), jnp.int32),
         interpret=interpret,
-    )(r)
-    return out[:jobs, :n_bins]
+    )(packed)

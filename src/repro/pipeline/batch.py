@@ -26,9 +26,19 @@ implementation) is a hard contract, pinned by a hypothesis property in
 ``SlotBuilder`` is the per-job view over one slot: it quacks exactly like a
 ``ProfileBuilder`` (``ingest``/``snapshot``/``finalize``/``spike_count``/
 ``fraction``/...), so ``OnlineCapController`` and the fleet controller drive
-it unchanged.  On TPU backends the commit-time histogram scatter runs through
-the batched Pallas kernel (``kernels.spike_hist.spike_hist_batch_pallas``);
-elsewhere (and by default in tests/CI) it is pure NumPy.
+it unchanged.
+
+On a TPU backend the histogram scatter of each tick's newly committed
+block counts on the device (promoted idle tails and the finalize flush stay
+host bincounts).  The host computes every spike sample's bin index in
+float64 with the NumPy branch's own expression and packs the indices of all
+bin sizes into one int32 per sample (``kernels.spike_hist.pack_fields``);
+one jitted Pallas call per tick (``kernels.ops.spike_hist_packed``) returns
+the integer counts of every row.  The device never bins a float, so both
+backends produce the same counts integer for integer, and autodetection
+does not change the arithmetic.  Rows and samples pad up to power-of-two
+buckets (``device_shape``), so a drive compiles a bounded set of programs,
+which ``warmup`` compiles up front.  Elsewhere the scatter is pure NumPy.
 
 Error semantics: the engine validates every chunk of a tick *before* mutating
 any slot, so a poisoned chunk leaves the whole tick's builders untouched
@@ -46,7 +56,19 @@ from repro.pipeline.builder import (DEFAULT_BIN_SIZES, EMA_BLOCK,
                                     _fold_trim, _validate_readings)
 from repro.telemetry.simulator import TelemetryChunk, TraceMeta
 
-__all__ = ["BatchProfileEngine", "SlotBuilder"]
+__all__ = ["BatchProfileEngine", "SlotBuilder", "device_shape"]
+
+#: smallest padded (rows, samples) of a device histogram call; both axes
+#: round up to a power of two at or above these floors
+DEVICE_ROW_FLOOR = 256
+DEVICE_SAMPLE_FLOOR = 256
+
+
+def device_shape(rows: int, samples: int) -> tuple[int, int]:
+    """The padded shape of a device histogram call over ``rows`` rows of
+    up to ``samples`` committed samples each."""
+    return (max(DEVICE_ROW_FLOOR, 1 << (int(rows) - 1).bit_length()),
+            max(DEVICE_SAMPLE_FLOOR, 1 << (int(samples) - 1).bit_length()))
 
 
 class SlotBuilder:
@@ -123,10 +145,11 @@ class BatchProfileEngine:
     def __init__(self, bin_sizes=DEFAULT_BIN_SIZES, alpha: float = 0.5,
                  ema_block: int = EMA_BLOCK, capacity: int = 64,
                  backend: str | None = None):
-        """``backend`` selects the commit-time histogram scatter: ``"numpy"``
-        (``np.add.at``), ``"pallas"`` (the batched TPU kernel), or ``None``
-        to autodetect — compiled Pallas on TPU, NumPy elsewhere (the same
-        convention as ``spikes.ema_filter``/``kernels.spike_hist``)."""
+        """``backend`` selects where the commit-time histogram scatter
+        counts: ``"numpy"`` (host bincount), ``"pallas"`` (one packed-index
+        Pallas call per tick; interpreted off-TPU), or ``None`` to
+        autodetect — the device on a TPU backend, NumPy elsewhere.  Both
+        give identical counts."""
         if not 0.0 < alpha <= 1.0:
             raise ValueError(f"alpha must be in (0, 1], got {alpha}")
         self.bin_sizes = tuple(float(c) for c in bin_sizes)
@@ -139,6 +162,10 @@ class BatchProfileEngine:
         self.w = 1.0 - self.alpha
         self.block = int(ema_block)
         self._backend = backend
+        self._fields = None          # packed-index layout of the device path
+        self._device_pending: list[tuple] = []   # this tick's device rows
+        self.device_calls = 0        # one per tick that commits a block
+        self.device_shapes: set[tuple[int, int]] = set()   # compiled shapes
         cap = max(int(capacity), 1)
         # columnar scalar state (one row per slot)
         self._tdp = np.zeros(cap, np.float64)
@@ -328,6 +355,7 @@ class BatchProfileEngine:
             idx, er2, br2, dt, d_e, d_b = grp.pop()
             self._advance_group(idx, er2, br2, dt, d_e, d_b, length, pend,
                                 has_state)
+        self._flush_device()
 
     def _advance_group(self, idx: np.ndarray, er2: np.ndarray,
                        br2: np.ndarray, dt: np.ndarray, d_e: np.ndarray,
@@ -460,16 +488,10 @@ class BatchProfileEngine:
                       mask: np.ndarray) -> None:
         """Accumulate the masked (k, F) relative-power block into every
         tracked histogram.  Counts are exact float64 integers, so the
-        scatter is bit-identical to per-piece ``np.bincount`` adds."""
-        if self._resolve_backend() == "pallas":
-            from repro.kernels.spike_hist import spike_hist_batch_pallas
-            masked = np.where(mask, r, -np.inf)
-            for c in self.bin_sizes:
-                h = self._hist[c]
-                counts = np.asarray(spike_hist_batch_pallas(
-                    masked, h.shape[1], lo=spikes.SPIKE_LO, bin_width=c))
-                h[idx] += counts.astype(np.float64)
-            return
+        scatter is bit-identical to per-piece ``np.bincount`` adds.  The
+        device path bins here too and queues the packed indices for the
+        tick's one device call (``_flush_device``)."""
+        device = self._resolve_backend() == "pallas"
         spike = r >= spikes.SPIKE_LO
         np.logical_and(spike, mask, out=spike)
         ri, ci = np.nonzero(spike)
@@ -484,27 +506,78 @@ class BatchProfileEngine:
         q = np.empty_like(shifted)
         bidx = np.empty(len(shifted), np.int64)
         flat = np.empty(len(shifted), np.int64)
-        for c in self.bin_sizes:
+        if device:
+            packed = np.zeros(len(shifted), np.int64)
+        for b, c in enumerate(self.bin_sizes):
             h = self._hist[c]
             n = h.shape[1]
             np.divide(shifted, c, out=q)
             np.copyto(bidx, q, casting="unsafe")  # C truncation == astype
             np.minimum(bidx, n - 1, out=bidx)     # quotients are >= 0
+            if device:
+                np.left_shift(bidx, self._fields[b][0], out=flat)
+                packed |= flat
+                continue
             np.multiply(ri, n, out=flat)
             flat += bidx
             # one flat bincount + dense row add: the same exact integer
             # counts as np.add.at, without its scattered read-modify-write
             counts = np.bincount(flat, minlength=k * n)
             h[idx] += counts.reshape(k, n)
+        if device:
+            self._device_pending.append((idx, ri, ci, packed, r.shape[1]))
+
+    def _flush_device(self) -> None:
+        """Count the tick's queued spike samples in one device call and add
+        the counts to every tracked histogram.  Slots are unique within a
+        tick, so the row add is a plain fancy-index add."""
+        pending, self._device_pending = self._device_pending, []
+        if not pending:
+            return
+        from repro.kernels.ops import spike_hist_packed
+        rows = sum(len(p[0]) for p in pending)
+        shape = device_shape(rows, max(p[4] for p in pending))
+        buf = np.full(shape, -1, np.int32)
+        r0 = 0
+        for idx, ri, ci, packed, _ in pending:
+            buf[r0 + ri, ci] = packed
+            r0 += len(idx)
+        slots = np.concatenate([p[0] for p in pending])
+        counts = np.asarray(spike_hist_packed(buf, self._fields))[:rows]
+        self.device_calls += 1
+        self.device_shapes.add(shape)
+        for (_, _, offset), c in zip(self._fields, self.bin_sizes):
+            h = self._hist[c]
+            h[slots] += counts[:, offset:offset + h.shape[1]]
+
+    def warmup(self, rows: int, samples: int = EMA_BLOCK) -> int:
+        """Compile the device histogram call for every padded shape a tick
+        of up to ``rows`` rows, each committing up to ``samples`` samples,
+        can take — so no compile lands inside a drive.  Returns the number
+        of shapes warmed (0 on the NumPy backend)."""
+        if self._resolve_backend() != "pallas":
+            return 0
+        from repro.kernels.ops import spike_hist_packed
+        top_rows, width = device_shape(rows, samples)
+        n_rows, warmed = DEVICE_ROW_FLOOR, 0
+        while n_rows <= top_rows:
+            shape = (n_rows, width)
+            np.asarray(spike_hist_packed(np.full(shape, -1, np.int32),
+                                         self._fields))
+            self.device_shapes.add(shape)
+            warmed += 1
+            n_rows *= 2
+        return warmed
 
     def _resolve_backend(self) -> str:
         if self._backend is None:
-            try:
-                import jax
-                self._backend = "pallas" \
-                    if jax.default_backend() == "tpu" else "numpy"
-            except Exception:        # jax unavailable: stay pure NumPy
-                self._backend = "numpy"
+            import jax
+            self._backend = "pallas" \
+                if jax.default_backend() == "tpu" else "numpy"
+        if self._backend == "pallas" and self._fields is None:
+            from repro.kernels.spike_hist import pack_fields
+            self._fields = pack_fields(
+                [self._hist[c].shape[1] for c in self.bin_sizes])
         return self._backend
 
     # -- incremental queries ---------------------------------------------

@@ -7,8 +7,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def _install_hypothesis_shim() -> None:
-    """Make ``from hypothesis import given, settings, strategies`` work in
-    containers without hypothesis installed.
+    """Make ``from hypothesis import example, given, settings, strategies``
+    work in containers without hypothesis installed.
 
     The shim is a deliberately tiny stand-in: ``@given`` draws a fixed number
     of pseudo-random examples from the strategies (deterministic seed, no
@@ -65,6 +65,8 @@ def _install_hypothesis_shim() -> None:
             # zero-arg wrapper (not functools.wraps): the strategy parameters
             # must not leak into the signature pytest inspects for fixtures
             def wrapper():
+                for kwargs in getattr(fn, "_shim_examples", ()):
+                    fn(**kwargs)
                 rng = _np.random.default_rng(0)
                 for _ in range(getattr(fn, "_shim_max_examples", 20)):
                     fn(*[s.draw(rng) for s in strategies])
@@ -81,12 +83,21 @@ def _install_hypothesis_shim() -> None:
             return fn
         return deco
 
+    def example(**kwargs):
+        """Explicit example; apply it below ``@given`` so the wrapper sees
+        it."""
+        def deco(fn):
+            fn._shim_examples = [kwargs, *getattr(fn, "_shim_examples", ())]
+            return fn
+        return deco
+
     mod = types.ModuleType("hypothesis")
     mod.__doc__ = "pytest-time fallback shim (see tests/conftest.py)"
     st_mod = types.ModuleType("hypothesis.strategies")
     for f in (floats, integers, sampled_from, lists, just, tuples, one_of):
         setattr(st_mod, f.__name__, f)
     mod.given = given
+    mod.example = example
     mod.settings = settings
     mod.strategies = st_mod
     sys.modules["hypothesis"] = mod

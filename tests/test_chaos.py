@@ -8,7 +8,7 @@ failure schedule.  Plus the satellite pins: ``ElasticPlan`` loss accounting,
 ``rescale_batch``'s per-device-batch contract, ``StragglerMonitor`` aging,
 and the ``core.baselines`` all-excluded contract."""
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.api import (MinosSession, OnlineCapController, ReferenceLibrary,
                        SessionReport, TPUPowerModel,
@@ -514,6 +514,9 @@ def test_from_config_stragglers(micro_library):
 @settings(max_examples=8, deadline=None)
 @given(st.lists(st.integers(min_value=0, max_value=3 * 3 * 12 - 1),
                 min_size=0, max_size=6))
+# fail then restore one device at the same chunk, with that device's chunk
+# still in flight for a job that migrated mid-profile
+@example(encoded=[91, 97])
 def test_budget_never_exceeded_across_any_failure_schedule(encoded):
     """Each encoded int unpacks to (chunk index 0..11, action 0..2,
     device 0..2); whatever the churn, every repack stays inside the

@@ -104,19 +104,27 @@ def test_flash_attention_matches_model_chunked_path():
                                              (9, 1000, 30, 0.05),
                                              (32, 257, 3, 0.5)])
 def test_spike_hist_batch_sweep(jobs, n, n_bins, c):
-    """Batched (jobs x samples) histogram kernel == per-row f32 binning;
-    -inf padding/masking never counted (the ragged-commit mask contract)."""
-    from repro.kernels.spike_hist import spike_hist_batch_pallas
+    """Batched (jobs x samples) packed-index counting kernel == per-row
+    float64 bincount of the host-computed bin indices; -1 padding/masking
+    is never counted, and a second histogram packed into the same int32
+    counts in its own lanes."""
+    from repro.kernels.spike_hist import pack_fields, spike_hist_packed_pallas
     rng = np.random.default_rng(jobs * 1000 + n)
-    r = rng.uniform(0.0, 2.5, size=(jobs, n)).astype(np.float32)
-    r = np.where(rng.random((jobs, n)) < 0.8, r, -np.inf).astype(np.float32)
-    got = np.asarray(spike_hist_batch_pallas(jnp.asarray(r), n_bins, lo=0.5,
-                                             bin_width=c, interpret=True))
-    want = np.zeros((jobs, n_bins), np.float32)
+    r = rng.uniform(0.0, 2.5, size=(jobs, n))
+    live = (rng.random((jobs, n)) < 0.8) & (r >= 0.5)
+    fields = pack_fields([n_bins, 15])
+    idx_a = np.minimum(((r - 0.5) / c).astype(np.int64), n_bins - 1)
+    idx_b = np.minimum(((r - 0.5) / 0.1).astype(np.int64), 14)
+    packed = np.where(live, (idx_a << fields[0][0]) | (idx_b << fields[1][0]),
+                      -1)
+    buf = np.full((-(-jobs // 8) * 8, -(-n // 128) * 128), -1, np.int32)
+    buf[:jobs, :n] = packed
+    got = np.asarray(spike_hist_packed_pallas(jnp.asarray(buf), fields,
+                                              interpret=True))[:jobs]
+    off = fields[1][2]
     for i in range(jobs):
-        row = r[i][r[i] >= 0.5]
-        idx = np.floor((row - np.float32(0.5)) / np.float32(c)) \
-            .astype(np.int32)
-        want[i] = np.bincount(np.minimum(idx, n_bins - 1),
-                              minlength=n_bins).astype(np.float32)
-    np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            got[i, :n_bins], np.bincount(idx_a[i][live[i]], minlength=n_bins))
+        np.testing.assert_array_equal(
+            got[i, off:off + 15], np.bincount(idx_b[i][live[i]], minlength=15))
+    assert not got[:, off + 15:].any()

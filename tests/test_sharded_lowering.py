@@ -55,12 +55,6 @@ _SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing seed failure: make_mesh_from_config uses "
-           "jax.sharding.AxisType, which this container's jax does not "
-           "expose (AttributeError in the lowering subprocess); passes on "
-           "newer jax, so not strict")
 def test_reduced_models_lower_on_4x4_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
