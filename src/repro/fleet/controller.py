@@ -45,6 +45,7 @@ from contextlib import nullcontext
 from dataclasses import dataclass, field
 from time import perf_counter
 
+import repro.obs as obs
 from repro.configs.base import MeshConfig
 from repro.core.classify import MinosClassifier
 from repro.fleet.inventory import FAILED, HEALTHY, DeviceInstance, \
@@ -398,17 +399,18 @@ class FleetCapController:
 
         Journal bytes, job state, and placement are identical to calling
         ``admit`` once per entry; only the store-flush count changes."""
-        taken: set[str] = set()
-        specs = [self._admit_validate(taken=taken, **kw)
-                 for kw in admissions]
-        ctx = self.journal.batch() if self.journal is not None \
-            else nullcontext()
-        with ctx:
-            for spec in specs:
-                self._journal_admit(spec)
-            for spec in specs:
-                self._admit_apply(spec)
-        self._sync_store()
+        with obs.span("admit"):
+            taken: set[str] = set()
+            specs = [self._admit_validate(taken=taken, **kw)
+                     for kw in admissions]
+            ctx = self.journal.batch() if self.journal is not None \
+                else nullcontext()
+            with ctx:
+                for spec in specs:
+                    self._journal_admit(spec)
+                for spec in specs:
+                    self._admit_apply(spec)
+            self._sync_store()
         return [spec["job_id"] for spec in specs]
 
     def _admit_validate(self, device: DeviceInstance, meta, chips: int = 1,
@@ -544,6 +546,10 @@ class FleetCapController:
         one closing re-pack.  Falls back to the sequential path per chunk
         when the chunk can't batch (per-job engine, duplicate job in one
         batch, straggler cadence monitoring — which is order-sensitive)."""
+        with obs.span("tick"):
+            return self._ingest_tick(batch)
+
+    def _ingest_tick(self, batch) -> list[CapDecision]:
         if self.straggler_adapter is not None:
             # cadence monitoring consumes chunks one at a time in wire
             # order; keep that path byte-identical
@@ -650,9 +656,10 @@ class FleetCapController:
         that already decided."""
         job = self.jobs[job_id]
         if job.decision is None:
-            self._decide(job, job.controller.finalize(job.builder))
-            self._repack()
-            self._sync_store()
+            with obs.span("finalize_job"):
+                self._decide(job, job.controller.finalize(job.builder))
+                self._repack()
+                self._sync_store()
         return job.decision
 
     def restart_profile(self, job_id: str, meta=None) -> None:
@@ -689,13 +696,14 @@ class FleetCapController:
         retirement never re-classifies anything."""
         if job_id not in self.jobs:    # KeyError on unknown/already-retired
             raise KeyError(job_id)
-        self._journal(kinds.RETIRE, job_id=job_id)
-        job = self.jobs.pop(job_id)
-        self._drop_builder(job.builder)
-        if job.plan is not None:
-            self._unpack(job.plan)
-            self._repack()
-        self._sync_store()
+        with obs.span("retire"):
+            self._journal(kinds.RETIRE, job_id=job_id)
+            job = self.jobs.pop(job_id)
+            self._drop_builder(job.builder)
+            if job.plan is not None:
+                self._unpack(job.plan)
+                self._repack()
+            self._sync_store()
         return job
 
     def set_budget(self, budget_w: float) -> None:

@@ -90,15 +90,15 @@ PINNED_DIRS = (
 #: stdlib-only and cycle-free.
 ALLOWED_EDGES = {
     "analysis": {"configs"},
-    "api": {"core", "discovery", "fleet", "ft", "pipeline", "sched",
+    "api": {"core", "discovery", "fleet", "ft", "obs", "pipeline", "sched",
             "store", "telemetry"},
     "checkpoint": set(),
     "configs": set(),
     "core": {"kernels", "pipeline"},
     "data": {"configs"},
     "discovery": {"analysis", "core", "pipeline", "telemetry"},
-    "fleet": {"analysis", "configs", "core", "ft", "pipeline", "sched",
-              "store", "telemetry"},
+    "fleet": {"analysis", "configs", "core", "ft", "obs", "pipeline",
+              "sched", "store", "telemetry"},
     "ft": {"configs"},
     "kernels": set(),
     "launch": {"analysis", "api", "configs", "models", "sched", "serve",
@@ -106,8 +106,9 @@ ALLOWED_EDGES = {
     "legacy": {"core", "telemetry"},
     "lint": set(),
     "models": {"configs"},
+    "obs": set(),
     "optim": {"configs"},
-    "pipeline": {"analysis", "core", "kernels", "telemetry"},
+    "pipeline": {"analysis", "core", "kernels", "obs", "telemetry"},
     "sched": {"analysis", "core"},
     "serve": {"configs", "models"},
     "store": set(),

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import repro.obs as obs
 from repro.core import spikes
 from repro.pipeline.builder import (DEFAULT_BIN_SIZES, EMA_BLOCK,
                                     PartialProfile, _ema_filter_block,
@@ -285,6 +286,25 @@ class BatchProfileEngine:
         error for bad telemetry matches the per-job ``ProfileBuilder``
         message for the first offending chunk in batch order.
         """
+        with obs.span("engine"):
+            with obs.span("engine.validate"):
+                groups = self._validate_batch(slots, chunks)
+            if not groups:
+                return
+            # phase 4: mutate, one stacked pass per group
+            with obs.span("engine.advance"):
+                for (length, pend, has_state), grp in groups.items():
+                    idx, er2, br2, dt, d_e, d_b = grp.pop()
+                    self._advance_group(idx, er2, br2, dt, d_e, d_b, length,
+                                        pend, has_state)
+            self._flush_device()
+
+    def _validate_batch(self, slots, chunks) -> dict:
+        """Phases 1-3 of ``ingest_batch``: check every chunk, group the rows
+        by (chunk length, pending count, EMA-state presence) and stack each
+        group's arrays, mutating nothing.  Returns the groups, each a list
+        of rows ending in its stacked arrays; empty when no chunk holds a
+        reading."""
         slots = list(slots)
         chunks = list(chunks)
         if len(slots) != len(chunks):
@@ -312,7 +332,7 @@ class BatchProfileEngine:
                 continue                    # empty chunk: a no-op
             rows.append((pos, s, chunk, er, br))
         if not rows:
-            return
+            return {}
         # phase 2: group rows so stacked 2D passes line up — equal chunk
         # length for the counter diff, equal pending count + state presence
         # for fixed-position EMA blocks
@@ -350,12 +370,7 @@ class BatchProfileEngine:
                                chunk.sample_dt, er, br)
             raise AssertionError("vectorized validation flagged a chunk the "
                                  "reference validator accepts")  # unreachable
-        # phase 4: mutate, one stacked pass per group
-        for (length, pend, has_state), grp in groups.items():
-            idx, er2, br2, dt, d_e, d_b = grp.pop()
-            self._advance_group(idx, er2, br2, dt, d_e, d_b, length, pend,
-                                has_state)
-        self._flush_device()
+        return groups
 
     def _advance_group(self, idx: np.ndarray, er2: np.ndarray,
                        br2: np.ndarray, dt: np.ndarray, d_e: np.ndarray,
@@ -535,20 +550,21 @@ class BatchProfileEngine:
         if not pending:
             return
         from repro.kernels.ops import spike_hist_packed
-        rows = sum(len(p[0]) for p in pending)
-        shape = device_shape(rows, max(p[4] for p in pending))
-        buf = np.full(shape, -1, np.int32)
-        r0 = 0
-        for idx, ri, ci, packed, _ in pending:
-            buf[r0 + ri, ci] = packed
-            r0 += len(idx)
-        slots = np.concatenate([p[0] for p in pending])
-        counts = np.asarray(spike_hist_packed(buf, self._fields))[:rows]
-        self.device_calls += 1
-        self.device_shapes.add(shape)
-        for (_, _, offset), c in zip(self._fields, self.bin_sizes):
-            h = self._hist[c]
-            h[slots] += counts[:, offset:offset + h.shape[1]]
+        with obs.span("engine.device"):
+            rows = sum(len(p[0]) for p in pending)
+            shape = device_shape(rows, max(p[4] for p in pending))
+            buf = np.full(shape, -1, np.int32)
+            r0 = 0
+            for idx, ri, ci, packed, _ in pending:
+                buf[r0 + ri, ci] = packed
+                r0 += len(idx)
+            slots = np.concatenate([p[0] for p in pending])
+            counts = np.asarray(spike_hist_packed(buf, self._fields))[:rows]
+            self.device_calls += 1
+            self.device_shapes.add(shape)
+            for (_, _, offset), c in zip(self._fields, self.bin_sizes):
+                h = self._hist[c]
+                h[slots] += counts[:, offset:offset + h.shape[1]]
 
     def warmup(self, rows: int, samples: int = EMA_BLOCK) -> int:
         """Compile the device histogram call for every padded shape a tick
@@ -719,6 +735,7 @@ class BatchProfileEngine:
                 traces.append(pieces[0])  # committed pieces are immutable
             else:
                 traces.append(np.concatenate(pieces))
+        obs.count("snapshot.samples", sum(map(len, traces)))
         rr = np.concatenate(rr_parts) if rr_parts else None
         rows = np.concatenate(row_parts) if rr_parts else None
         mats = self._memo_mats(idx, rr, rows)

@@ -16,6 +16,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+import repro.obs as obs
 from repro.core.algorithm1 import (DEFAULT_BIN_CANDIDATES, FreqSelection,
                                    cap_perf_centric, cap_power_centric,
                                    resolve_objective, select_optimal_freq)
@@ -303,6 +304,11 @@ def observe_fleet(pairs) -> list:
     all its identical siblings.  Returns the per-pair ``CapDecision |
     None`` list; each decision is bit-identical to what that pair's
     ``observe`` call would have produced."""
+    with obs.span("classify"):
+        return _observe_fleet(pairs)
+
+
+def _observe_fleet(pairs) -> list:
     out = [None] * len(pairs)
     # engine-backed slot builders gate and snapshot columnar: one stacked
     # spike-count row-sum and one snapshot_batch per engine, instead of a
@@ -319,24 +325,25 @@ def observe_fleet(pairs) -> list:
             # first-appearance order and keys are never serialized
             by_engine.setdefault(id(eng), []).append(i)  # minoslint: disable=W304
             engines[id(eng)] = eng  # minoslint: disable=W304
-    for key, ids in by_engine.items():
-        eng = engines[key]
-        counts = eng.spike_count_batch([pairs[i][1].slot for i in ids])
-        passing_ids = [
-            i for i, cnt in zip(ids, counts.tolist())
-            if cnt >= pairs[i][0].min_spike_samples
-            and pairs[i][1].fraction >= pairs[i][0].min_fraction]
-        gated.update(ids)
-        reps: list[int] = []
-        first: dict[tuple, int] = {}
-        for i in passing_ids:
-            r = first.setdefault(_replica_key(*pairs[i]), i)
-            if r == i:
-                reps.append(i)
-            else:
-                replicas.setdefault(r, []).append(i)
-        snap.update(zip(reps, eng.snapshot_batch(
-            [pairs[i][1].slot for i in reps])))
+    with obs.span("classify.snapshot"):
+        for key, ids in by_engine.items():
+            eng = engines[key]
+            counts = eng.spike_count_batch([pairs[i][1].slot for i in ids])
+            passing_ids = [
+                i for i, cnt in zip(ids, counts.tolist())
+                if cnt >= pairs[i][0].min_spike_samples
+                and pairs[i][1].fraction >= pairs[i][0].min_fraction]
+            gated.update(ids)
+            reps: list[int] = []
+            first: dict[tuple, int] = {}
+            for i in passing_ids:
+                r = first.setdefault(_replica_key(*pairs[i]), i)
+                if r == i:
+                    reps.append(i)
+                else:
+                    replicas.setdefault(r, []).append(i)
+            snap.update(zip(reps, eng.snapshot_batch(
+                [pairs[i][1].slot for i in reps])))
     passing = []                 # (i, controller, builder, profile)
     for i, (ctl, builder) in enumerate(pairs):
         if i in snap:
@@ -353,18 +360,25 @@ def observe_fleet(pairs) -> list:
         if len(profile.power_trace) == 0:
             continue
         passing.append((i, ctl, builder, profile))
-    for group in _grouped(passing):
-        results = classify_with_margin_batch(
-            [p for _, _, _, p in group], group[0][1].clf,
-            group[0][1].bin_candidates)
-        for (i, ctl, builder, profile), (sel, conf) in zip(group, results):
-            if conf >= ctl.min_confidence:
-                out[i] = ctl._record(profile, builder, sel, conf, early=True)
-            for j in replicas.get(i, ()):
-                ctl_j, b_j = pairs[j]
-                if conf >= ctl_j.min_confidence:
-                    out[j] = ctl_j._record(profile, b_j, sel, conf,
-                                           early=True)
+    decided = 0
+    with obs.span("classify.sweep"):
+        for group in _grouped(passing):
+            results = classify_with_margin_batch(
+                [p for _, _, _, p in group], group[0][1].clf,
+                group[0][1].bin_candidates)
+            for (i, ctl, builder, profile), (sel, conf) in zip(group,
+                                                               results):
+                if conf >= ctl.min_confidence:
+                    out[i] = ctl._record(profile, builder, sel, conf,
+                                         early=True)
+                    decided += 1
+                for j in replicas.get(i, ()):
+                    ctl_j, b_j = pairs[j]
+                    if conf >= ctl_j.min_confidence:
+                        out[j] = ctl_j._record(profile, b_j, sel, conf,
+                                               early=True)
+    obs.count("classify.swept", len(passing))
+    obs.count("classify.decided", decided)
     return out
 
 
