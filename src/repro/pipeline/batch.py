@@ -48,6 +48,8 @@ tick before raising) — the raised message is byte-identical to the per-job
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 import repro.obs as obs
@@ -70,6 +72,128 @@ def device_shape(rows: int, samples: int) -> tuple[int, int]:
     up to ``samples`` committed samples each."""
     return (max(DEVICE_ROW_FLOOR, 1 << (int(rows) - 1).bit_length()),
             max(DEVICE_SAMPLE_FLOOR, 1 << (int(samples) - 1).bit_length()))
+
+
+#: the trace quantile every emitted profile carries (``choose_bin_size``'s)
+PQ = 90.0
+#: ``np.percentile``'s own fraction: ``q / 100`` in float64
+_PQ_FRAC = float(np.true_divide(PQ, 100.0))
+#: the rank window keeps about this top share of the committed trace, with
+#: one EMA block of slack on either side
+_WINDOW_SHARE = 0.15
+_WINDOW_SLACK = EMA_BLOCK
+_EMPTY = np.empty(0, np.float64)
+
+
+class _RankWindow:
+    """Exact upper order statistics of one slot's committed trace.
+
+    Holds every committed sample at or above ``theta``, sorted, and the
+    count of those below it.  ``absorb`` folds in only the committed pieces
+    appended since the last call (the pieces are immutable), so its work
+    grows with the new samples and the window, not with the whole trace.
+    ``percentile`` takes the two order statistics ``np.percentile``'s
+    linear method interpolates between, across the window and a snapshot's
+    uncommitted extras, and combines them with numpy's own ``_lerp``
+    expression: the result equals ``np.percentile(trace, PQ)`` bit for bit.
+    When a rank falls below ``theta`` (the trace's power stepped down), the
+    window is rebuilt from the whole committed trace, and the counter
+    ``snapshot.pq_rebuilds`` counts it."""
+
+    __slots__ = ("win", "theta", "below", "pieces")
+
+    def __init__(self):
+        self.win = _EMPTY
+        self.theta = -np.inf
+        self.below = 0
+        self.pieces = 0
+
+    @staticmethod
+    def _keep(n: int) -> int:
+        """The window size to trim to over ``n`` committed samples."""
+        return n - max(0, int((1.0 - _WINDOW_SHARE) * n) - _WINDOW_SLACK)
+
+    def absorb(self, committed: list) -> None:
+        k = len(committed)
+        if self.pieces == k:
+            return
+        x = committed[-1] if k - self.pieces == 1 \
+            else np.concatenate(committed[self.pieces:])
+        self.pieces = k
+        if self.theta != -np.inf:
+            hi = x[x >= self.theta]
+            self.below += len(x) - len(hi)
+            x = hi
+        if len(x):
+            # timsort merges the sorted window with the new run
+            win = np.concatenate((self.win, x))
+            win.sort(kind="stable")
+            self.win = win
+        keep = self._keep(self.below + len(self.win))
+        if len(self.win) > keep + _WINDOW_SLACK:
+            # theta rises to the lowest copy of the value at the cut, so
+            # every sample left below it is strictly smaller
+            cut = int(self.win.searchsorted(self.win[-keep]))
+            if cut:
+                self.theta = float(self.win[cut])
+                self.below += cut
+                self.win = self.win[cut:]
+
+    def _rebuild(self, committed: list, room: int) -> None:
+        """Reset the window over the whole committed trace, with at most
+        ``room`` samples below ``theta``."""
+        obs.count("snapshot.pq_rebuilds")
+        x = np.concatenate(committed)
+        t = min(len(x) - self._keep(len(x)), room)
+        if t > 0:
+            x.partition(t)
+            self.theta = float(x[t])
+            hi = x[x >= self.theta]
+            self.below = len(x) - len(hi)
+        else:
+            self.theta, hi, self.below = -np.inf, x, 0
+        hi.sort()
+        self.win = hi
+        self.pieces = len(committed)
+
+    def percentile(self, committed: list, extras: np.ndarray) -> float:
+        """``np.percentile`` at ``PQ`` of the committed trace followed by
+        ``extras`` (absorbs ``committed`` first)."""
+        self.absorb(committed)
+        m = len(extras)
+        n = self.below + len(self.win) + m
+        if n == 0:
+            return 0.0
+        vi = (n - 1) * _PQ_FRAC                  # numpy's virtual index
+        if vi >= n - 1:                          # one sample: numpy takes
+            lo = hi = n - 1                      # index -1 twice, and its
+            gamma = vi + 1.0                     # gamma is vi - (-1)
+        else:
+            lo = math.floor(vi)
+            hi = lo + 1
+            gamma = vi - lo
+        ex = extras[extras >= self.theta] if m else extras
+        under = self.below + m - len(ex)
+        if lo < under:
+            self._rebuild(committed, lo - m)
+            ex = extras[extras >= self.theta] if m else extras
+            under = self.below + m - len(ex)
+        r0, r1 = lo - under, hi - under
+        if len(ex):
+            # window samples below rank r0 - len(ex) precede every extra
+            # at or above rank r0, and those past r1 follow them: the two
+            # ranks lie in the merge of this slice with the extras
+            s = max(0, r0 - len(ex))
+            part = np.concatenate((self.win[s:r1 + 1], ex))
+            part.sort()
+            a, b = float(part[r0 - s]), float(part[r1 - s])
+        else:
+            a, b = float(self.win[r0]), float(self.win[r1])
+        # numpy's _lerp(a, b, gamma), in the same float64 operations
+        diff = b - a
+        if gamma >= 0.5:
+            return b - diff * (1.0 - gamma)
+        return a + diff * gamma
 
 
 class SlotBuilder:
@@ -189,6 +313,8 @@ class BatchProfileEngine:
         self._busyq: list[list[np.ndarray]] = [[] for _ in range(cap)]
         self._tail: list[list[np.ndarray]] = [[] for _ in range(cap)]
         self._committed: list[list[np.ndarray]] = [[] for _ in range(cap)]
+        # order statistics of each committed trace, for the emitted p90
+        self._rank: list[_RankWindow | None] = [None] * cap
         self._free: list[int] = list(range(cap - 1, -1, -1))
 
     # -- capacity --------------------------------------------------------
@@ -220,6 +346,7 @@ class BatchProfileEngine:
             self._hist[c] = np.vstack(
                 [h, np.zeros((add, h.shape[1]), np.float64)])
         self._meta.extend([None] * add)
+        self._rank.extend([None] * add)
         for lst in (self._pending, self._busyq, self._tail, self._committed):
             lst.extend([] for _ in range(add))
         self._free.extend(range(new - 1, old - 1, -1))
@@ -249,6 +376,7 @@ class BatchProfileEngine:
         self._busyq[s] = []
         self._tail[s] = []
         self._committed[s] = []
+        self._rank[s] = _RankWindow()
         return s
 
     def builder(self, meta: TraceMeta, tdp: float) -> SlotBuilder:
@@ -270,6 +398,7 @@ class BatchProfileEngine:
             self._busyq[slot] = []
             self._tail[slot] = []
             self._committed[slot] = []
+            self._rank[slot] = None
             self._free.append(slot)
 
     def _check_live(self, slot: int) -> None:
@@ -662,7 +791,19 @@ class BatchProfileEngine:
         trace = np.concatenate(pieces) if pieces else np.empty(0, np.float64)
         prof = self._profile(slot, trace, complete=False)
         self._prefill_spike_memo(prof, slot, extras)
+        self._prefill_pq(prof, slot,
+                         np.concatenate(extras) if extras else _EMPTY)
         return prof
+
+    def _prefill_pq(self, prof: PartialProfile, slot: int,
+                    extras: np.ndarray) -> None:
+        """Seed the profile's ``p_quantile`` memo at ``PQ`` from the slot's
+        rank window, over the committed trace and the snapshot's uncommitted
+        ``extras``: the same float ``spikes.p_quantile`` computes from the
+        whole trace."""
+        v = self._rank[slot].percentile(self._committed[slot], extras)
+        prof.__dict__["_pq_memo"] = {PQ: v / prof.tdp}
+        obs.count("snapshot.pq_prefilled")
 
     def _memo_mats(self, idx: np.ndarray, rr: np.ndarray | None,
                    rows: np.ndarray | None) -> dict[float, np.ndarray]:
@@ -707,6 +848,7 @@ class BatchProfileEngine:
             raise ValueError(f"slot {bad} is not allocated")
         npend = self._n_pending[idx].tolist()
         traces: list[np.ndarray] = []
+        ex_all = [_EMPTY] * k             # each row's uncommitted extras
         rr_parts: list[np.ndarray] = []
         row_parts: list[np.ndarray] = []
         empty = np.empty(0, np.float64)
@@ -722,9 +864,9 @@ class BatchProfileEngine:
                         list(self._tail[s]))
                     if extras:
                         pieces = pieces + extras
-                        r = np.concatenate(extras) if len(extras) > 1 \
-                            else extras[0]
-                        r = r / self._tdp[s]
+                        ex_all[j] = np.concatenate(extras) \
+                            if len(extras) > 1 else extras[0]
+                        r = ex_all[j] / self._tdp[s]
                         r = r[r >= spikes.SPIKE_LO]
                         if len(r):
                             rr_parts.append(r)
@@ -745,6 +887,7 @@ class BatchProfileEngine:
             prof = self._profile(s, traces[j], complete=False)
             prof.__dict__["_spike_memo"] = {c: mats[c][j] for c in bins}
             prof.__dict__["_spike_mat"] = (mats, j)
+            self._prefill_pq(prof, s, ex_all[j])
             out.append(prof)
         return out
 
@@ -820,6 +963,7 @@ class BatchProfileEngine:
         prof = self._profile(slot, trace, complete=True)
         # after the flush the histograms cover the whole committed trace
         self._prefill_spike_memo(prof, slot, [])
+        self._prefill_pq(prof, slot, _EMPTY)
         return prof
 
     def finalize_batch(self, slots) -> list[PartialProfile]:
@@ -894,5 +1038,6 @@ class BatchProfileEngine:
             prof = self._profile(s, traces[j], complete=True)
             prof.__dict__["_spike_memo"] = {c: mats[c][j] for c in bins}
             prof.__dict__["_spike_mat"] = (mats, j)
+            self._prefill_pq(prof, s, _EMPTY)
             out.append(prof)
         return out

@@ -60,7 +60,10 @@ def _batch_quantiles(profiles, q: float) -> None:
     """Prefill each profile's ``p_quantile`` memo with row-wise percentiles
     over equal-length trace stacks.  ``np.percentile(..., axis=1)`` computes
     each row independently of the others, so every prefetched value is
-    bit-identical to the per-trace call the memo would otherwise make."""
+    bit-identical to the per-trace call the memo would otherwise make.
+    Profiles a ``BatchProfileEngine`` emits arrive with the memo filled from
+    their slot's order statistics and are skipped; this is the path of
+    per-job ``ProfileBuilder`` profiles and references."""
     q = float(q)
     by_len: dict[int, list] = {}
     for p in profiles:
@@ -168,13 +171,15 @@ class OnlineCapController:
     least ``min_fraction`` of the expected trace, and margin confidence at or
     above ``min_confidence`` — or unconditionally at ``finalize``.
 
-    Cost note: every ``observe`` runs full Algorithm 1 on the snapshot —
-    O(trace-so-far), since ``choose_bin_size`` needs trace quantiles, not
-    just the builder's incremental histograms (the snapshot memoizes its
-    spike vectors so the bin-size sweep, neighbor, and margin queries share
-    one histogram pass per bin size).  At the shipped 1 kHz sampling that is
-    microseconds per chunk; raise ``min_spike_samples``/``min_fraction`` or
-    observe every k-th chunk if sampling orders of magnitude faster.
+    Cost note: every ``observe`` runs full Algorithm 1 on the snapshot.
+    The snapshot memoizes its spike vectors, so the bin-size sweep,
+    neighbor, and margin queries share one histogram pass per bin size.
+    ``choose_bin_size`` also needs the trace's p90: a per-job
+    ``ProfileBuilder`` snapshot takes it with ``np.percentile`` over the
+    trace-so-far, O(trace); a ``BatchProfileEngine`` slot's snapshot
+    carries it from the slot's incremental order statistics: the new
+    samples merge into a sorted window of about the trace's top 15%.  The
+    snapshot itself still concatenates the trace-so-far.
     """
 
     def __init__(self, references, objective="powercentric",

@@ -424,3 +424,54 @@ def test_fleet_batched_engine_matches_perjob_engine(micro_library):
     assert coarse.decisions == ref.decisions
     assert coarse.schedule == ref.schedule
     assert coarse.repacks <= ref.repacks
+
+
+@pytest.mark.parametrize("engine", ["batched", "perjob"])
+def test_engine_profiles_carry_their_p90(micro_library, monkeypatch, engine):
+    """An engine-minted profile's p90 comes from its slot's order
+    statistics: the sweep (``observe_fleet``), ``finalize_job`` and
+    ``finalize_fleet`` take no percentile over its trace.  A per-job
+    ``ProfileBuilder``'s profile still takes ``np.percentile``."""
+    from repro.core import spikes
+    from repro.pipeline import online
+
+    for ref in micro_library:          # the references' memo, filled once
+        ref.p_quantile(90.0)
+    calls = []
+    p_quantile, percentile = spikes.p_quantile, np.percentile
+
+    def spy_p_quantile(power, tdp, q=90.0):
+        calls.append("p_quantile")
+        return p_quantile(power, tdp, q)
+
+    def spy_percentile(a, q, *args, **kwargs):
+        calls.append("percentile")
+        return percentile(a, q, *args, **kwargs)
+
+    monkeypatch.setattr(spikes, "p_quantile", spy_p_quantile)
+    monkeypatch.setattr(online.np, "percentile", spy_percentile)
+    inv = DeviceInventory.generate(4, VariabilityModel(), seed=7)
+    # no early decision clears the gate: every tick sweeps every job, and
+    # every decision comes from a stream end
+    fleet = FleetCapController(micro_library, budget_w=5000.0, engine=engine,
+                               **{**GATES, "min_confidence": 1.01})
+    mux = FleetTelemetryMux()
+    jobs = []
+    for fn, dev in zip((micro_gemm, micro_spmv_memory, micro_spmv_compute,
+                        micro_idle_burst), inv):
+        meta, chunks = _job_stream(fn, seed=len(jobs),
+                                   device_id=dev.device_id)
+        jobs.append(fleet.admit(dev, meta, chips=4))
+        mux.add_job(jobs[-1], meta, chunks)
+    swept = 0
+    for k, batch in enumerate(mux.ticks()):
+        fleet.ingest_tick(batch)
+        swept += 1
+        if k == 2:
+            fleet.finalize_job(jobs[0])          # one stream end alone
+    result = fleet.finalize()                    # the rest in one sweep
+    assert swept > 3 and len(result.decisions) == len(jobs)
+    if engine == "batched":
+        assert calls == []
+    else:
+        assert "percentile" in calls

@@ -7,6 +7,7 @@ import os
 import time
 import types
 
+import numpy as np
 import pytest
 
 import repro.obs as obs
@@ -16,6 +17,7 @@ from repro.api import (DeviceInventory, MinosSession, ReferenceLibrary,
 from repro.pipeline.batch import BatchProfileEngine
 from repro.store import journal as journal_mod
 from repro.store.journal import JOURNAL_FILE
+from repro.telemetry.simulator import TelemetryChunk, TraceMeta
 from repro.telemetry.kernel_stream import (micro_gemm, micro_idle_burst,
                                            micro_spmv_compute,
                                            micro_spmv_memory, micro_stencil)
@@ -190,3 +192,60 @@ def test_outputs_are_byte_identical_with_tracing_on_and_off(
     assert on == off
     assert _journal_digest(str(tmp_path / "on")) \
         == _journal_digest(str(tmp_path / "off"))
+
+
+def test_pq_counters_count_the_engine_profiles(library, monkeypatch):
+    """``snapshot.pq_prefilled`` counts every profile the engine emits (each
+    carries its p90 from the slot); nothing is recorded while off."""
+    emitted = []
+    profile = BatchProfileEngine._profile
+
+    def counted(self, *args, **kwargs):
+        emitted.append(1)
+        return profile(self, *args, **kwargs)
+
+    monkeypatch.setattr(BatchProfileEngine, "_profile", counted)
+    _drive(library)
+    assert emitted and obs.report() == {"spans": {}, "counters": {}}
+    emitted.clear()
+    obs.enable()
+    _drive(library)
+    c = obs.report()["counters"]
+    assert c["snapshot.pq_prefilled"] == len(emitted)
+
+
+def _slot_stream(eng, power):
+    n = len(power)
+    e = np.concatenate([[0.0], np.cumsum(power * 1e-3)])
+    b = np.arange(n + 1) * 1e-3
+    meta = TraceMeta(name="slot", domain="test", sample_dt=1e-3,
+                     n_samples=n, exec_time=1.0, app_sm_util=0.5,
+                     app_dram_util=0.5, kernel_rows=[])
+    slot = eng.alloc(meta, 200.0)
+    for i in range(0, n, 256):
+        eng.ingest_batch([slot], [TelemetryChunk(
+            energy_j=e[i + 1:i + 257], busy_s=b[i + 1:i + 257],
+            sample_dt=1e-3, start_index=i)])
+        eng.snapshot_batch([slot])
+    eng.finalize(slot)
+
+
+@pytest.mark.parametrize("shape", ["stationary", "step_down"])
+def test_pq_rebuilds_count_windows_rebuilt(shape):
+    """A stationary trace never rebuilds the rank window; power that steps
+    down from above TDP to a fifth of it does."""
+    rng = np.random.default_rng(3)
+    n, high = 12_000, 1_500
+    if shape == "stationary":
+        power = rng.uniform(100.0, 260.0, n)
+    else:
+        power = np.concatenate([rng.uniform(200.0, 260.0, high),
+                                rng.uniform(20.0, 60.0, n - high)])
+    obs.enable()
+    _slot_stream(BatchProfileEngine(), power)
+    c = obs.report()["counters"]
+    assert c["snapshot.pq_prefilled"] == -(-n // 256) + 1
+    if shape == "stationary":
+        assert "snapshot.pq_rebuilds" not in c
+    else:
+        assert c["snapshot.pq_rebuilds"] >= 1

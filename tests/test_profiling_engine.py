@@ -269,3 +269,190 @@ def test_spike_hist_partial_block_rows(n):
     p = jax.random.uniform(jax.random.key(n), (n,), minval=0.4, maxval=2.2)
     got = np.asarray(spike_hist_pallas(p, 15, interpret=True))
     assert got.sum() == pytest.approx(float(np.sum(np.asarray(p) >= 0.5)))
+
+
+# ---------------------------------------------------------------------------
+# engine: the p90 an emitted profile carries from its slot
+# ---------------------------------------------------------------------------
+from hypothesis import given, settings, strategies as st  # noqa: E402
+
+from repro.pipeline.batch import BatchProfileEngine  # noqa: E402
+from repro.telemetry.simulator import TelemetryChunk, TraceMeta  # noqa: E402
+
+DT = 0.5     # integer watts times DT stay exact through the counters
+
+
+def _stream(rng, n, shape, name):
+    """Counter chunks of an ``n``-sample trace: uniform power, a step down
+    from above TDP to well below it, or a few integer levels (ties)."""
+    if shape == "uniform":
+        power = rng.uniform(0.0, 1.3 * TDP, n)
+    elif shape == "step":
+        k = int(rng.integers(0, n + 1))
+        power = np.concatenate([rng.uniform(0.9 * TDP, 1.3 * TDP, k),
+                                rng.uniform(0.05 * TDP, 0.45 * TDP, n - k)])
+    else:
+        power = rng.integers(0, 7, n) * 40.0
+    busy = (rng.random(n) < 0.85).astype(float)
+    e = np.concatenate([[0.0], np.cumsum(power * DT)])
+    b = np.concatenate([[0.0], np.cumsum(busy * DT)])
+    meta = TraceMeta(name=name, domain="test", sample_dt=DT, n_samples=n,
+                     exec_time=1.0, app_sm_util=0.5, app_dram_util=0.5,
+                     kernel_rows=[])
+    bounds, i = [0], 0
+    while i < n:
+        i = min(n, i + int(rng.integers(1, 600)))
+        bounds.append(i)
+    chunks = [TelemetryChunk(energy_j=e[i + 1:j + 1], busy_s=b[i + 1:j + 1],
+                             sample_dt=DT, start_index=i)
+              for i, j in zip(bounds[:-1], bounds[1:])]
+    return meta, chunks
+
+
+def _assert_p90_exact(prof):
+    """The engine filled the memo itself, with the float the reference path
+    (``np.percentile`` over the whole trace, over TDP) computes."""
+    got = prof.__dict__["_pq_memo"][90.0]
+    want = spikes.p_quantile(prof.power_trace, prof.tdp, 90.0)
+    assert got.hex() == want.hex(), (len(prof.power_trace), got, want)
+
+
+@given(st.integers(min_value=0, max_value=10_000))
+@settings(max_examples=20, deadline=None)
+def test_slot_p90_is_np_percentile_bit_for_bit(scenario_seed):
+    """Random chunkings, every tick's snapshots (with their pending
+    extras), mid-stream retires with slot reuse, and both finalize paths:
+    every emitted profile's p90 equals ``np.percentile`` bit for bit."""
+    rng = np.random.default_rng(scenario_seed)
+    eng = BatchProfileEngine(capacity=2)         # force slot-array growth
+    shapes = ("uniform", "step", "ties")
+
+    def new_job(name):
+        n = int(rng.integers(1, 3000))
+        meta, chunks = _stream(rng, n, shapes[int(rng.integers(3))], name)
+        return dict(slot=eng.alloc(meta, TDP), chunks=chunks, pos=0)
+
+    live = {f"j{k}": new_job(f"j{k}") for k in range(int(rng.integers(2, 5)))}
+    admits_left, next_id = 3, 100
+    while live:
+        ids = sorted(live)
+        tick = [j for j in ids if live[j]["pos"] < len(live[j]["chunks"])
+                and rng.random() < 0.7]
+        if tick:
+            eng.ingest_batch([live[j]["slot"] for j in tick],
+                             [live[j]["chunks"][live[j]["pos"]]
+                              for j in tick])
+            for j in tick:
+                live[j]["pos"] += 1
+        for prof in eng.snapshot_batch([live[j]["slot"] for j in ids]):
+            _assert_p90_exact(prof)
+        _assert_p90_exact(eng.snapshot(live[ids[0]]["slot"]))
+        if rng.random() < 0.1:                   # retire mid-stream
+            eng.free(live.pop(ids[int(rng.integers(len(ids)))])["slot"])
+            if admits_left:                      # the freed slot is reused
+                admits_left -= 1
+                live[f"n{next_id}"] = new_job(f"n{next_id}")
+                next_id += 1
+        done = [j for j in sorted(live)
+                if live[j]["pos"] >= len(live[j]["chunks"])]
+        if not done:
+            continue
+        slots = [live.pop(j)["slot"] for j in done]
+        if rng.random() < 0.5:
+            profs = eng.finalize_batch(slots)
+        else:
+            profs = [eng.finalize(s) for s in slots]
+        for prof, s in zip(profs, slots):
+            assert prof.complete
+            _assert_p90_exact(prof)
+            eng.free(s)
+
+
+def _step_down_slot(eng, n=12000, high=1500):
+    rng = np.random.default_rng(5)
+    power = np.concatenate([rng.uniform(1.0 * TDP, 1.3 * TDP, high),
+                            rng.uniform(0.1 * TDP, 0.3 * TDP, n - high)])
+    e = np.concatenate([[0.0], np.cumsum(power * DT)])
+    b = np.arange(n + 1) * DT
+    meta = TraceMeta(name="step", domain="test", sample_dt=DT, n_samples=n,
+                     exec_time=1.0, app_sm_util=0.5, app_dram_util=0.5,
+                     kernel_rows=[])
+    slot = eng.alloc(meta, TDP)
+    chunks = [TelemetryChunk(energy_j=e[i + 1:i + 301],
+                             busy_s=b[i + 1:i + 301], sample_dt=DT,
+                             start_index=i) for i in range(0, n, 300)]
+    return slot, chunks
+
+
+def test_slot_p90_after_a_step_down_rebuilds_the_window():
+    """Power that drops from above TDP to a fifth of it pushes the p90's
+    ranks below the window's threshold: the window is rebuilt from the
+    whole trace, and every snapshot's p90 stays exact."""
+    import repro.obs as obs
+
+    eng = BatchProfileEngine()
+    slot, chunks = _step_down_slot(eng)
+    obs.reset()
+    obs.enable()
+    try:
+        for chunk in chunks:
+            eng.ingest_batch([slot], [chunk])
+            _assert_p90_exact(eng.snapshot_batch([slot])[0])
+        _assert_p90_exact(eng.finalize(slot))
+        assert obs.report()["counters"]["snapshot.pq_rebuilds"] >= 1
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+@pytest.mark.parametrize("n", [1, 2])
+@pytest.mark.parametrize("busy", [True, False])
+def test_slot_p90_of_one_and_two_samples(n, busy):
+    eng = BatchProfileEngine()
+    power = np.array([150.0, 230.0])[:n]
+    e = np.concatenate([[0.0], np.cumsum(power * DT)])
+    b = np.concatenate([[0.0], np.cumsum(np.full(n, DT if busy else 0.0))])
+    meta = TraceMeta(name="tiny", domain="test", sample_dt=DT, n_samples=n,
+                     exec_time=1.0, app_sm_util=0.5, app_dram_util=0.5,
+                     kernel_rows=[])
+    a, c = eng.alloc(meta, TDP), eng.alloc(meta, TDP)
+    chunk = TelemetryChunk(energy_j=e[1:], busy_s=b[1:], sample_dt=DT,
+                           start_index=0)
+    eng.ingest_batch([a, c], [chunk, chunk])
+    snap = eng.snapshot(a)
+    assert len(snap.power_trace) == (n if busy else 0)
+    _assert_p90_exact(snap)
+    _assert_p90_exact(eng.snapshot_batch([a])[0])
+    _assert_p90_exact(eng.finalize(a))
+    _assert_p90_exact(eng.finalize_batch([c])[0])
+
+
+def test_rank_window_rounds_like_numpy_lerp():
+    """Traces whose two order statistics ``a < b`` make numpy's two lerp
+    forms round apart (it takes ``b - diff * (1 - gamma)`` from gamma 0.5
+    up): the window, fed in random pieces with uncommitted extras, gives
+    ``np.percentile``'s float in every case."""
+    from repro.pipeline.batch import _RankWindow
+
+    rng = np.random.default_rng(11)
+    cases = 0
+    while cases < 60:
+        n = int(rng.integers(2, 4000))
+        vi = (n - 1) * 0.9
+        lo = int(np.floor(vi))
+        gamma = vi - lo
+        a, b = np.sort(rng.uniform(0.0, 300.0, 2)).tolist()
+        if a + (b - a) * gamma == b - (b - a) * (1.0 - gamma):
+            continue
+        cases += 1
+        x = np.concatenate([np.full(lo + 1, a), np.full(n - lo - 1, b)])
+        x += rng.uniform(-1.0, 0.0, n) * (x == a) * (np.arange(n) < lo)
+        rng.shuffle(x)
+        m = int(rng.integers(0, min(n, 256)))
+        cuts = np.sort(rng.integers(0, n - m + 1, 3))
+        pieces = [p for p in np.split(x[:n - m], cuts) if len(p)]
+        window = _RankWindow()
+        for k in range(1, len(pieces)):
+            window.absorb(pieces[:k])
+        got = window.percentile(pieces, x[n - m:])
+        assert got.hex() == float(np.percentile(x, 90.0)).hex()
