@@ -20,8 +20,36 @@ trace always in it), and on the whole live population for the packing:
   * ``window_compiles``: programs compiled inside the window (0), and
     ``device_calls``: device histogram calls in it (at least 1), with at
     least one decision sampled and one histogram compared.
+
+Decisions are compared on the trace and device of the run they came from;
+placements and power where the program binds each job now
+(``FleetJob.device``), so that a job that migrated is held to where it
+runs.  A configuration with a ``faults`` section adds, each with its limit
+in the cell's check file:
+
+  * ``failed_placements``: jobs whose plan (placed or deferred) binds
+    them to a device that is down, after each ``fail_device`` and
+    ``restore_device`` call and at the close;
+  * ``migration_classify_calls``: classifier calls made inside
+    ``fail_device`` and ``restore_device`` (counted as
+    ``repro.core.classify.count_classifier_calls`` counts them): migration
+    re-costs cached decisions and never classifies;
+  * ``lost_runs``: streams that ended with no answer because the program
+    never took back a run that a migration cut, and undecided live jobs
+    whose engine holds another number of samples than the wire sent them
+    in their current run;
+  * ``failures`` and ``restarts`` in the window: at least 1 each.
+
+A ``store`` section adds ``resume_gap``: after the window, with the store
+neither closed nor flushed (a crash), ``MinosSession.resume`` rebuilds the
+session; live jobs whose decision (cap and neighbours), device, or placed
+or deferred status differ between the two, plus classifier calls made
+during the resume.  Every acknowledged write has to be read back.
 """
 from __future__ import annotations
+
+import time
+import traceback
 
 import numpy as np
 
@@ -30,6 +58,7 @@ from bench.traffic import generator as gen
 
 UPPER = ("hist_gap", "decision_gap", "confidence_gap", "placement_gap",
          "violations", "window_compiles")
+FAULT_UPPER = ("failed_placements", "migration_classify_calls", "lost_runs")
 
 
 def effective_tdp(device) -> float:
@@ -157,7 +186,7 @@ def placement_check(cell, lib: ref.Library) -> tuple[dict, list]:
         if fj.decision is None:
             continue
         job = cell.jobs[jid]
-        dev = job.tele.device
+        dev = fj.device
         plans.append(dict(job_id=jid, name=job.tele.stream.name,
                           device_id=dev.device_id, chips=job.chips,
                           cap=float(fj.decision.cap),
@@ -185,7 +214,7 @@ def violations_check(cell, placed: list) -> dict:
     for jid in placed:
         job = cell.jobs[jid]
         fj = cell.fleet.jobs[jid]
-        dev = job.tele.device
+        dev = fj.device
         key = (job.tele.stream.name, dev.model, dev.spec.perf_scale,
                dev.spec.power_scale, float(fj.decision.cap))
         g = groups.setdefault(key, [job.tele.stream, dev, 0])
@@ -202,11 +231,82 @@ def violations_check(cell, placed: list) -> dict:
     return dict(violations=n, peak_sustained_w=peak, groups=len(groups))
 
 
+def lost_runs(cell, rec) -> int:
+    """Streams that ended unanswered, and undecided live jobs whose engine
+    holds another number of samples than the wire sent in their run."""
+    lost = rec.unanswered
+    for jid, job in cell.jobs.items():
+        if job.decided:
+            continue
+        sent = int(job.tele.chunk_end[job.k - 1]) if job.k else 0
+        fj = cell.fleet.jobs[jid]
+        lost += fj.needs_reprofile or fj.builder.n_ingested != sent
+    return lost
+
+
+def resume_check(cell) -> dict:
+    """Resume the session from its store as a crash leaves it, and count
+    the live jobs the resumed session holds otherwise, plus classifier
+    calls made by the resume."""
+    from repro.api import MinosSession
+    from repro.core.classify import MinosClassifier, count_classifier_calls
+    counts = []
+    init = MinosClassifier.__init__
+
+    def counted_init(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        counts.append(count_classifier_calls(self))
+
+    MinosClassifier.__init__ = counted_init
+    t = time.perf_counter()
+    try:
+        resumed = MinosSession.resume(cell.store_path, references=cell.lib)
+    except Exception:
+        # a store that cannot be resumed has lost every live job
+        traceback.print_exc()
+        return dict(resume_gap=len(cell.fleet.jobs) + 1,
+                    resume_s=time.perf_counter() - t,
+                    resume_classify_calls=None)
+    finally:
+        MinosClassifier.__init__ = init
+    resume_s = time.perf_counter() - t
+    calls = sum(c["n"] for c in counts)
+    handles = resumed.jobs
+    live, back = cell.fleet, resumed.report().schedule
+
+    def statuses(schedule) -> dict:
+        if schedule is None:
+            return {}
+        out = {p.job_id: "placed" for p in schedule.placed}
+        out.update((jid, "deferred") for jid in schedule.deferred)
+        return out
+
+    def decision(d):
+        if d is None:
+            return None
+        sel = d.selection
+        return (float(d.cap), sel.power_neighbor, sel.util_neighbor)
+
+    was = statuses(live.repacks[-1] if live.repacks else None)
+    now = statuses(back)
+    gap = len(set(handles) - set(live.jobs))
+    for jid, fj in live.jobs.items():
+        h = handles.get(jid)
+        gap += h is None or (
+            decision(fj.decision) != decision(h.decision(finalize=False))
+            or fj.device.device_id != h.device.device_id
+            or was.get(jid) != now.get(jid))
+    resumed.store.close()
+    return dict(resume_gap=gap + calls, resume_s=resume_s,
+                resume_classify_calls=calls)
+
+
 def run_checks(cell, rec, limits: dict, control_dtype=None
                ) -> tuple[bool, dict, dict]:
     """(correct, compared numbers with their limits, other figures).
     With ``control_dtype`` the reference in that precision stands in for
     the program's decisions: the control, which has to come out false."""
+    resumed = resume_check(cell) if cell.store is not None else None
     lib = reference_library(cell.cfg)
     control = (None if control_dtype is None
                else reference_library(cell.cfg, control_dtype))
@@ -218,18 +318,33 @@ def run_checks(cell, rec, limits: dict, control_dtype=None
                   placement_gap=pl["placement_gap"],
                   violations=vio["violations"],
                   window_compiles=rec.compiles)
-    checks = {}
-    ok = True
-    for name in UPPER:
-        lim = float(limits[name])
-        checks[name] = dict(value=values[name], limit=lim)
-        ok &= values[name] <= lim
-    for name, value in (("device_calls", rec.device_calls),
-                        ("sampled", dec["sampled"]),
-                        ("hist_sampled", dec["hist_sampled"])):
-        checks[name] = dict(value=value, limit=1, at_least=True)
-        ok &= value >= 1
+    upper = list(UPPER)
+    at_least = [("device_calls", rec.device_calls),
+                ("sampled", dec["sampled"]),
+                ("hist_sampled", dec["hist_sampled"])]
     other = dict(plans=pl["plans"], placed=pl.get("placed"),
                  peak_sustained_w=vio["peak_sustained_w"],
                  budget_w=cell.budget_w, groups=vio["groups"])
+    if cell.faults is not None:
+        values.update(
+            failed_placements=cell.failed_placements + cell.plans_on_down(),
+            migration_classify_calls=cell.fault_classify_calls,
+            lost_runs=lost_runs(cell, rec))
+        upper += FAULT_UPPER
+        at_least += [("failures", rec.failures), ("restarts", rec.restarts)]
+        other.update(down_at_close=len(cell.faults.failed))
+    if resumed is not None:
+        values["resume_gap"] = resumed["resume_gap"]
+        upper.append("resume_gap")
+        other.update(resume_s=resumed["resume_s"],
+                     resume_classify_calls=resumed["resume_classify_calls"])
+    checks = {}
+    ok = True
+    for name in upper:
+        lim = float(limits[name])
+        checks[name] = dict(value=values[name], limit=lim)
+        ok &= values[name] <= lim
+    for name, value in at_least:
+        checks[name] = dict(value=value, limit=1, at_least=True)
+        ok &= value >= 1
     return bool(ok), checks, other

@@ -5,14 +5,15 @@
         --trace 0
 
 Builds the cell's system and traffic from the seed (set-up, timed as
-``setup_s``), measures for ``--seconds``, checks what the window produced
-against the plain reference, and prints one JSON object as the last line
-of standard output: ``correct``, ``attempted``, ``failed``, ``metrics``
-(the cell's end-to-end metrics, or with ``--trace 1`` its per-layer
-metrics), ``device``, with ``--trace 1`` ``breakdown``, and last the
-compared numbers beside their limits under ``checks``.  Exits non-zero,
-printing no result, where JAX finds no TPU or fewer chips than the cell
-asks for.
+``setup_s``), measures for ``--seconds`` (with ``--trace 1`` under the
+profiler and the program's own recorder, ``repro.obs``), checks what the
+window produced against the plain reference, and prints one JSON object
+as the last line of standard output: ``correct``, ``attempted``,
+``failed``, ``metrics`` (the cell's end-to-end metrics, or with
+``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1``
+``breakdown``, and last the compared numbers beside their limits under
+``checks``.  Exits non-zero, printing no result, where JAX finds no TPU
+or fewer chips than the cell asks for.
 """
 import time
 
@@ -80,13 +81,17 @@ def require_chips(n: int):
     return devices
 
 
-def layer_record(rec, spans, reduced, peaks) -> dict:
+def layer_record(rec, spans, reduced, peaks, program, idle_by_span) -> dict:
+    """What the readers of the per-layer metrics read: the window's
+    record, the benchmark's spans, the reduced device trace, the program's
+    own spans and counters (``program``) and the device-idle time by
+    innermost program span (``idle_by_span``)."""
     return dict(window_s=rec.window_s, decisions=rec.decisions,
                 events=rec.events, spans=dict(spans.total),
                 pack_in_tick_s=spans.pack_in_tick, repack_s=rec.repack_s,
                 wire_late_ms=list(rec.wire_late_ms),
                 hist_calls=list(spans.hist_calls), trace=reduced,
-                peaks=peaks)
+                peaks=peaks, program=program, idle_by_span=idle_by_span)
 
 
 def main(argv=None) -> int:
@@ -99,7 +104,8 @@ def main(argv=None) -> int:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, ROOT)
     sys.path.insert(0, os.path.join(ROOT, "src"))
-    from bench import check, harness, spans as spans_mod, tracing
+    from bench import check, harness, program_trace, spans as spans_mod
+    from bench import tracing
     spec = harness.load_cell(args.workload)
     devices = require_chips(int(spec["cell"]["chips"]))
     import jax
@@ -125,14 +131,19 @@ def main(argv=None) -> int:
         tracing.start(trace_dir)
     setup_s = time.perf_counter() - T_START
     if spans:
+        recorder = program_trace.start()
         with spans.span("window"):
             rec = cell.run(args.seconds, counter)
+        program = program_trace.finish(recorder)
     else:
         rec = cell.run(args.seconds, counter)
-    reduced = None
+    reduced = idle_by_span = None
     if args.trace:
         tracing.stop()
-        reduced = tracing.reduce(tracing.load(trace_dir))
+        profile = tracing.load(trace_dir)
+        reduced = tracing.reduce(profile)
+        if program is not None:
+            idle_by_span = program_trace.attribute(profile)
     mem = memory_peak_bytes(devices)
     print(f"set-up: import {t_import!r} s, library "
           f"{cell.setup['library_s']!r} s, telemetry "
@@ -153,6 +164,14 @@ def main(argv=None) -> int:
           f"{p95(rec.wire_late_ms[:third])!r}, "
           f"{p95(rec.wire_late_ms[-third:]) if third else None!r}",
           flush=True)
+    if cell.faults is not None:
+        print(f"faults: {rec.failures} failures, {rec.restarts} restarts "
+              f"in the window, {len(cell.faults.failed)} devices down at "
+              f"the close; {rec.paused_s!r} s off the window clock "
+              f"(restart traces, placement checks)", flush=True)
+    if cell.store is not None:
+        print(f"store: {rec.journal_records} journal records, "
+              f"{rec.snapshots} snapshots in the window", flush=True)
     metas = {id(fj.builder.meta) for fj in cell.fleet.jobs.values()}
     print(f"telemetry: {len(metas)} distinct TraceMeta objects over "
           f"{len(cell.fleet.jobs)} live jobs", flush=True)
@@ -163,13 +182,17 @@ def main(argv=None) -> int:
           flush=True)
     workload = args.workload
     if args.trace:
-        layer = layer_record(rec, spans, reduced, peaks)
+        layer = layer_record(rec, spans, reduced, peaks, program,
+                             idle_by_span)
         metrics = {}
         for m in listed(spec["spec"]["per_layer"], workload):
             v = read_layer(m["name"], layer)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         print(f"trace: {json.dumps(reduced)}", flush=True)
+        print(f"program spans: {json.dumps(program)}", flush=True)
+        print(f"idle by program span: {json.dumps(idle_by_span)}",
+              flush=True)
     else:
         values = end_to_end(rec, setup_s)
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}

@@ -34,16 +34,48 @@ OPEN_METRICS = {
          "better": "lower", "workloads": ["hpc.steady"]}]}
 
 
+# A durable, failure-heavy deployment has no cell yet: the rehearsal runs
+# `helios` with a `store` and a `faults` section from these entries, under
+# each loop, with hpc.replay's check settings and the limits of the checks
+# those sections add.  The rate is set for the 36-node cluster of
+# `small_spec`: about 3.6 failures per telemetry second, 4 of its 36
+# devices down at a time.
+DURABLE = {"hpc.durable": "replay", "hpc.durable_steady": "steady"}
+STORE = {"fsync": True, "snapshot_every": 25, "rotate_every": 10000}
+FAULTS = {"failures_per_device_s": 0.1, "repair_s": 1.0}
+DURABLE_LIMITS = {"failed_placements": 0, "migration_classify_calls": 0,
+                  "lost_runs": 0, "resume_gap": 0}
+
+
 _load_cell = harness.load_cell     # before pretend_chip patches it
 
 
 def load_cell(workload: str) -> dict:
+    if workload in DURABLE:
+        return durable_cell(workload)
     if workload != OPEN["name"]:
         return _load_cell(workload)
     spec = harness.load_json(ROOT, "BENCHMARK.json")
     for key, extra in OPEN_METRICS.items():
         spec[key] = spec[key] + extra
     return harness.cell_spec(spec, OPEN)
+
+
+def durable_cell(workload: str) -> dict:
+    """``helios`` with ``STORE`` and ``FAULTS`` under the entry's loop,
+    reporting the loop's end-to-end metrics."""
+    traffic = DURABLE[workload]
+    base = load_cell({"replay": "hpc.replay", "steady": "hpc.steady"}[traffic])
+    spec = base["spec"]
+    for key in ("end_to_end", "per_layer"):
+        spec[key] = [dict(m, workloads=m["workloads"] + [workload])
+                     if base["cell"]["name"] in m.get("workloads", ())
+                     else m for m in spec[key]]
+    base["cell"] = {"name": workload, "config": "helios",
+                    "traffic": traffic, "chips": 1}
+    base["config"].update(store=dict(STORE), faults=dict(FAULTS))
+    base["check"]["limits"].update(DURABLE_LIMITS)
+    return base
 
 
 def small_spec(workload: str, nodes: int = 36, rate: float = 40.0) -> dict:
@@ -61,8 +93,9 @@ def small_spec(workload: str, nodes: int = 36, rate: float = 40.0) -> dict:
 
 
 
-def pretend_chip(monkeypatch, spec_fn=small_spec):
-    """Steer a run onto the CPU at a tiny size."""
+def pretend_chip(monkeypatch, spec_fn=small_spec, out=None):
+    """Steer a run onto the CPU at a tiny size; with ``out``, the run's
+    stores go there, apart from other tests' runs."""
     import jax
 
     import repro.api
@@ -78,6 +111,8 @@ def pretend_chip(monkeypatch, spec_fn=small_spec):
     monkeypatch.setattr(repro.api, "enable_compilation_cache",
                         lambda: "(off)")
     monkeypatch.setattr(harness, "load_cell", spec_fn)
+    if out is not None:
+        monkeypatch.setattr(harness, "OUT", str(out))
 
 
 def result(capsys, argv) -> dict:

@@ -1,13 +1,11 @@
 """The program's own spans in a traced run: the recorder switched on and
 read around the window, device-idle time cut by the innermost ``minos.*``
 span over it, and the readers of the metrics built on them."""
-import os
-
 import pytest
 from jax.profiler import ProfileData
 
 import bench_rehearsal as R
-from bench import harness, program_trace, run, tracing
+from bench import program_trace, run, tracing
 
 # times in ps from each line's base (ns): a 10 ms window, the device busy
 # over [1,4] and [7,8] ms, so idle over [0,1], [4,7] and [8,10] ms; the
@@ -110,7 +108,8 @@ def test_a_program_without_a_recorder_gives_nothing(monkeypatch):
 NEW = ("snapshot_ms_per_job.replay", "snapshot_samples_per_job.replay",
        "classifier_ms_per_job.replay", "classify_yield.replay",
        "finalize_ms_per_job.replay", "lifecycle_ms_per_job.replay",
-       "device_call_ms_per_job.replay", "unspanned_ms_per_job.replay")
+       "device_call_ms_per_job.replay", "unspanned_ms_per_job.replay",
+       "pq_rebuild_share.replay")
 
 
 def _span(total):
@@ -127,7 +126,9 @@ LAYER = dict(
                        "engine.device": _span(0.05)},
              "counters": {"snapshot.samples": 2_500_000,
                           "classify.swept": 4000,
-                          "classify.decided": 800}},
+                          "classify.decided": 800,
+                          "snapshot.pq_prefilled": 4000,
+                          "snapshot.pq_rebuilds": 20}},
     idle_by_span={"spans": {"classify.sweep": 0.3}, "unspanned_s": 0.7,
                   "devices": 1})
 
@@ -140,7 +141,8 @@ LAYER = dict(
     ("finalize_ms_per_job.replay", 0.25),
     ("lifecycle_ms_per_job.replay", 0.25),
     ("device_call_ms_per_job.replay", 0.05),
-    ("unspanned_ms_per_job.replay", 0.7)])
+    ("unspanned_ms_per_job.replay", 0.7),
+    ("pq_rebuild_share.replay", 0.5)])
 def test_program_readers(name, value):
     assert run.read_layer(name, LAYER) == pytest.approx(value)
 
@@ -148,7 +150,8 @@ def test_program_readers(name, value):
 def test_program_readers_return_nothing_without_input():
     empty = dict(LAYER, decisions=0)
     for name in NEW:
-        if name != "classify_yield.replay":         # not per job
+        if name not in ("classify_yield.replay",    # not per job
+                        "pq_rebuild_share.replay"):
             assert run.read_layer(name, empty) is None, name
     bare = dict(LAYER, program={"spans": {}, "counters": {}},
                 idle_by_span=dict(LAYER["idle_by_span"], devices=0))
@@ -156,50 +159,21 @@ def test_program_readers_return_nothing_without_input():
         assert run.read_layer(name, bare) is None, name
 
 
-def _wire_program(monkeypatch, workload):
-    """What a traced run needs to report the program's metrics: the
-    recorder on around the window, its report and the idle attribution in
-    the layer record, and the metrics listed for the cell."""
-    reports = []
-    window = harness.Cell.run
-
-    def traced_window(self, seconds, counter=None):
-        rec = program_trace.start()
-        try:
-            return window(self, seconds, counter)
-        finally:
-            reports.append(program_trace.finish(rec))
-
-    record = run.layer_record
-
-    def layer_record(*args):
-        layer = record(*args)
-        layer["program"] = reports[-1]
-        layer["idle_by_span"] = program_trace.attribute(tracing.load(
-            os.path.join(run.OUT, "trace", workload)))
-        return layer
-
-    def spec_fn(name):
-        spec = R.small_spec(name)
-        spec["spec"]["per_layer"] = spec["spec"]["per_layer"] + [
-            {"name": n, "unit": "-", "workloads": [workload]} for n in NEW]
-        return spec
-
-    monkeypatch.setattr(harness.Cell, "run", traced_window)
-    monkeypatch.setattr(run, "layer_record", layer_record)
-    R.pretend_chip(monkeypatch, spec_fn)
-
-
 def test_traced_rehearsal_reports_the_program_metrics(monkeypatch, capsys):
-    _wire_program(monkeypatch, "hpc.replay")
+    """``bench/run.py`` itself switches the recorder on around a traced
+    window and reports every program metric that ``BENCHMARK.json`` lists
+    for the cell."""
+    R.pretend_chip(monkeypatch)
     out = R.result(capsys, R.args("hpc.replay", seed=2**31 + 5, trace=1))
     assert out["correct"] is True
     got = out["metrics"]
     # the CPU has no device plane, so no device-idle time to attribute
-    for name in NEW[:-1]:
-        assert got[name]["value"] is not None, name
+    for name in NEW:
+        if name != "unspanned_ms_per_job.replay":
+            assert got[name]["value"] is not None, name
     assert "unspanned_ms_per_job.replay" not in got
     assert 0.0 < got["classify_yield.replay"]["value"] <= 100.0
+    assert 0.0 <= got["pq_rebuild_share.replay"]["value"] <= 100.0
     # the existing per-layer metrics read as before
     assert {"engine_ms_per_job.replay", "classify_ms_per_job.replay",
             "pack_ms_per_job.replay", "tick_self_ms_per_job.replay"} <= set(got)

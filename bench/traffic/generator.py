@@ -238,9 +238,11 @@ class Telemetry:
                          device_id=self.device.device_id)
 
 
-def make_pool(jobs, devices, tele: dict, seed: int) -> list[Telemetry]:
+def make_pool(jobs, devices, tele: dict, seed: int,
+              noise=None) -> list[Telemetry]:
     """One trace per job: the event trace is shared per (workload, device
-    spec) and each job draws its own noise from ``(seed, job index)``."""
+    spec) and each job draws its own noise from ``(seed, job index)``, or
+    from ``(seed, noise[i])`` where ``noise`` gives the indices."""
     from repro.telemetry.simulator import TelemetryChunk
     dt, cs = float(tele["sample_dt_s"]), int(tele["chunk_samples"])
     events: dict = {}
@@ -252,7 +254,8 @@ def make_pool(jobs, devices, tele: dict, seed: int) -> list[Telemetry]:
         if ev is None:
             ev = events[key] = event_trace(stream, 1.0, dev.power_model(),
                                            dt, float(tele["profile_s"]))
-        e = energy_counter(ev, float(tele["noise"]), [seed, i])
+        e = energy_counter(ev, float(tele["noise"]),
+                           [seed, i if noise is None else int(noise[i])])
         b = ev.busy_ctr
         starts = range(0, ev.n_samples, cs)
         chunks = [TelemetryChunk(energy_j=e[j + 1:min(j + cs, ev.n_samples)
