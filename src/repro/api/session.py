@@ -522,6 +522,14 @@ class MinosSession:
         return session
 
     @property
+    def fleet(self) -> FleetCapController:
+        """The session's ``FleetCapController``, for a fleet front end that
+        drives it tick by tick (``admit_many``, ``ingest_tick``,
+        ``finalize_job``, ``fail_device``, ...).  Its mutations are
+        journaled to the session's store like the session's own."""
+        return self._fleet
+
+    @property
     def engine(self):
         """The fleet's shared ``BatchProfileEngine``: ``engine.warmup(n)``
         compiles its device histogram programs for up to ``n`` concurrent

@@ -734,11 +734,13 @@ class FleetCapController:
         Returns this failure's events (also appended to ``self.events``)."""
         inv = self._require_inventory("fail_device")
         inv.get(device_id)                   # KeyError on unknown device
-        self._journal(kinds.FAIL, device=device_id)
-        inv.mark_failed(device_id)
-        self._failed_devices.add(device_id)
-        events = self._drain_device(device_id, FleetEvent("fail", device_id))
-        self._sync_store()
+        with obs.span("fault.fail"):
+            self._journal(kinds.FAIL, device=device_id)
+            inv.mark_failed(device_id)
+            self._failed_devices.add(device_id)
+            events = self._drain_device(device_id,
+                                        FleetEvent("fail", device_id))
+            self._sync_store()
         return events
 
     def degrade_device(self, device_id: str) -> list[FleetEvent]:
@@ -766,37 +768,41 @@ class FleetCapController:
         placements stay where they are (migration is one-way)."""
         inv = self._require_inventory("restore_device")
         prior = inv.health(device_id)
-        self._journal(kinds.RESTORE, device=device_id)
-        inv.restore(device_id)
-        self._failed_devices.discard(device_id)
-        events = [FleetEvent("restore", device_id, detail=f"was {prior}")]
-        replaced = False
-        for job in self.jobs.values():
-            health = inv.health(job.device.device_id)
-            if job.decision is not None and job.plan is None:
-                # stranded (by a fail, or a degrade drain that found no
-                # target): capacity is back, put it somewhere
-                if health == HEALTHY:
-                    # its own device is back
-                    self._set_plan(job, self._plan_for(job))
-                    if job.actuator is not None:
-                        job.actuator.set_cap(job.decision.cap)
-                    events.append(FleetEvent(
-                        "migrate", job.device.device_id, job_id=job.job_id,
-                        to_device_id=job.device.device_id,
-                        detail="re-placed after restore"))
-                else:
+        with obs.span("fault.restore"):
+            self._journal(kinds.RESTORE, device=device_id)
+            inv.restore(device_id)
+            self._failed_devices.discard(device_id)
+            events = [FleetEvent("restore", device_id,
+                                 detail=f"was {prior}")]
+            replaced = False
+            for job in self.jobs.values():
+                health = inv.health(job.device.device_id)
+                if job.decision is not None and job.plan is None:
+                    # stranded (by a fail, or a degrade drain that found no
+                    # target): capacity is back, put it somewhere
+                    if health == HEALTHY:
+                        # its own device is back
+                        self._set_plan(job, self._plan_for(job))
+                        if job.actuator is not None:
+                            job.actuator.set_cap(job.decision.cap)
+                        events.append(FleetEvent(
+                            "migrate", job.device.device_id,
+                            job_id=job.job_id,
+                            to_device_id=job.device.device_id,
+                            detail="re-placed after restore"))
+                    else:
+                        events.append(self._migrate_job(
+                            job, job.device.device_id))
+                    replaced = True
+                elif job.decision is None and health == FAILED:
+                    # mid-profile resident of a dead device: re-bind it so
+                    # its re-run lands on live silicon
                     events.append(self._migrate_job(job,
                                                     job.device.device_id))
-                replaced = True
-            elif job.decision is None and health == FAILED:
-                # mid-profile resident of a dead device: re-bind it so its
-                # re-run lands on live silicon
-                events.append(self._migrate_job(job, job.device.device_id))
-        self._emit(events)
-        if replaced:
-            self._repack()
-        self._sync_store()
+            self._emit(events)
+            if replaced:
+                self._repack()
+            self._sync_store()
         return events
 
     def device_health(self) -> dict[str, str]:
@@ -857,9 +863,10 @@ class FleetCapController:
                       if d.device_id not in exclude]
         if not candidates:
             return None
-        load = self._placement_load_w()
-        return min(candidates,
-                   key=lambda d: (load.get(d.device_id, 0.0), d.device_id))
+        with obs.span("fault.pick_target"):
+            load = self._placement_load_w()
+            return min(candidates, key=lambda d: (load.get(d.device_id, 0.0),
+                                                  d.device_id))
 
     def _rebind(self, job: FleetJob, device: DeviceInstance) -> None:
         """Point a job's actuation + decision tagging at a new device and
@@ -900,6 +907,8 @@ class FleetCapController:
             self._replace_builder(job, tdp=target.effective_tdp_w)
             job.needs_reprofile = True
             detail = "reprofile"
+            obs.count("fault.reprofiled")
+        obs.count("fault.migrated")
         self._rebind(job, target)
         job.devices = (target,)
         return FleetEvent("migrate", from_device_id, job_id=job.job_id,
@@ -934,6 +943,7 @@ class FleetCapController:
                 # trace is unfinishable — restart on the new primary
                 self._replace_builder(job)
                 job.needs_reprofile = True
+                obs.count("fault.reprofiled")
         if job.decision is not None:
             self._set_plan(job, self.scheduler.migrate_plan(
                 job.plan or self._plan_for(job), job.device,

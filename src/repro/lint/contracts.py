@@ -86,8 +86,8 @@ PINNED_DIRS = (
 
 #: the allowed package DAG under ``repro``: package -> packages it may
 #: import.  ``core`` never importing ``api`` (or ``fleet``) is the
-#: north-star edge; ``store`` importing nothing keeps durability
-#: stdlib-only and cycle-free.
+#: north-star edge; ``store`` importing nothing but the stdlib-only
+#: recorder ``obs`` keeps durability stdlib-only and cycle-free.
 ALLOWED_EDGES = {
     "analysis": {"configs"},
     "api": {"core", "discovery", "fleet", "ft", "obs", "pipeline", "sched",
@@ -111,7 +111,7 @@ ALLOWED_EDGES = {
     "pipeline": {"analysis", "core", "kernels", "obs", "telemetry"},
     "sched": {"analysis", "core"},
     "serve": {"configs", "models"},
-    "store": set(),
+    "store": {"obs"},
     "telemetry": {"analysis", "configs", "core", "pipeline"},
     "train": {"checkpoint", "configs", "data", "ft", "models", "optim"},
 }

@@ -9,7 +9,8 @@ listed in the DAG like any other.
 
 * **W401** — a module in package P imports ``repro.Q`` with Q outside
   ``ALLOWED_EDGES[P]``.  The north-star edge this guards: ``core`` (and
-  everything below it) never imports ``api``; ``store`` imports nothing.
+  everything below it) never imports ``api``; ``store`` imports only
+  the stdlib-only recorder ``obs``.
 * **W402** — a facade file (examples, fleet benchmarks) imports a
   ``repro`` module outside the public surface (``repro.api`` /
   ``repro.fleet``).

@@ -49,6 +49,8 @@ import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass
 
+import repro.obs as obs
+
 JOURNAL_FILE = "journal.jsonl"
 
 _CANONICAL = dict(sort_keys=True, separators=(",", ":"), allow_nan=False)
@@ -198,25 +200,35 @@ class EventJournal:
         Inside a ``batch()`` block (and without ``fsync``) the flush is
         deferred to batch exit, coalescing one syscall per record into one
         per tick; recovery already tolerates a torn batched tail exactly
-        like any torn record."""
-        seq = self._seq + 1
-        # ts is informational wall-clock metadata, never replayed into
-        # session state; deterministic callers pin it via the parameter
-        ts = time.time() if ts is None else float(ts)  # minoslint: disable=W301
-        rec = {"seq": seq, "ts": ts, "kind": str(kind), "data": data}
-        rec["sha"] = _checksum(seq, ts, rec["kind"], data)
-        fh = self._handle()
-        fh.write(json.dumps(rec, **_CANONICAL) + "\n")
-        if self._batch_depth and not self.fsync:
-            self._dirty = True
-        else:
-            fh.flush()
-            if self.fsync:
-                os.fsync(fh.fileno())
-        self._seq = seq
-        self._segment_records += 1
-        if self.rotate_every and self._segment_records >= self.rotate_every:
-            self._rotate()
+        like any torn record.
+
+        Recorded (``repro.obs``) as the span ``journal.append``, checksum,
+        encoding, write, flush and fsync included, and the counters
+        ``journal.records``, ``journal.bytes`` and ``journal.fsyncs``."""
+        with obs.span("journal.append"):
+            seq = self._seq + 1
+            # ts is informational wall-clock metadata, never replayed into
+            # session state; deterministic callers pin it via the parameter
+            ts = time.time() if ts is None else float(ts)  # minoslint: disable=W301
+            rec = {"seq": seq, "ts": ts, "kind": str(kind), "data": data}
+            rec["sha"] = _checksum(seq, ts, rec["kind"], data)
+            line = json.dumps(rec, **_CANONICAL) + "\n"
+            fh = self._handle()
+            fh.write(line)
+            if self._batch_depth and not self.fsync:
+                self._dirty = True
+            else:
+                fh.flush()
+                if self.fsync:
+                    os.fsync(fh.fileno())
+                    obs.count("journal.fsyncs")
+            self._seq = seq
+            self._segment_records += 1
+            if self.rotate_every \
+                    and self._segment_records >= self.rotate_every:
+                self._rotate()
+        obs.count("journal.records")
+        obs.count("journal.bytes", len(line))     # ASCII: one byte a char
         return seq
 
     def _rotate(self) -> None:
@@ -229,6 +241,7 @@ class EventJournal:
             fh.flush()
             if self.fsync:
                 os.fsync(fh.fileno())
+                obs.count("journal.fsyncs")
             fh.close()
             self._fh = None
         self._dirty = False

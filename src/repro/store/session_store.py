@@ -25,6 +25,8 @@ from __future__ import annotations
 import os
 from contextlib import contextmanager
 
+import repro.obs as obs
+
 from .journal import JOURNAL_FILE, EventJournal, JournalRecord
 from .snapshots import SnapshotStore
 
@@ -192,11 +194,13 @@ class SessionStore:
         zero-arg callable returning the JSON-ready session state (defaults
         to the attached ``self.capture``); it runs only when a snapshot is
         actually written.  With no capture available the due flag persists,
-        so the next flush with one still writes."""
+        so the next flush with one still writes.  Capture and write are
+        recorded (``repro.obs``) as the span ``store.snapshot``."""
         capture = capture if capture is not None else self.capture
         if not (self._snapshot_due or force) or capture is None:
             return False
-        self.snapshots.write(capture(), self.journal.last_seq)
+        with obs.span("store.snapshot"):
+            self.snapshots.write(capture(), self.journal.last_seq)
         self._since_snapshot = 0
         self._snapshot_due = False
         if self.compact_every and self._since_compact >= self.compact_every:

@@ -22,6 +22,8 @@ import re
 import time
 import warnings
 
+import repro.obs as obs
+
 SNAPSHOT_RETAIN = 2              # latest + one fallback (N-1 rollback)
 
 _CANONICAL = dict(sort_keys=True, separators=(",", ":"), allow_nan=False)
@@ -59,7 +61,8 @@ class SnapshotStore:
     # -- writing ---------------------------------------------------------
     def write(self, state, seq: int, ts: float | None = None) -> str:
         """Atomically persist ``state`` as the snapshot at journal ``seq``
-        and prune beyond the retention window.  Returns the file path."""
+        and prune beyond the retention window.  Returns the file path.
+        Its size is the ``repro.obs`` counter ``snapshot.bytes``."""
         os.makedirs(self.directory, exist_ok=True)
         # ts is informational metadata (recovery keys on seq, not ts);
         # deterministic callers pin it via the parameter
@@ -70,6 +73,7 @@ class SnapshotStore:
         tmp = path + ".tmp"
         with open(tmp, "w", encoding="utf-8") as f:
             json.dump(payload, f, **_CANONICAL)
+            obs.count("snapshot.bytes", f.tell())
             f.flush()
             if self.fsync:
                 os.fsync(f.fileno())

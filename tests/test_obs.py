@@ -249,3 +249,134 @@ def test_pq_rebuilds_count_windows_rebuilt(shape):
         assert "snapshot.pq_rebuilds" not in c
     else:
         assert c["snapshot.pq_rebuilds"] >= 1
+
+
+# spans and counters of the durable and fault paths
+DURABLE_SPANS = {"journal.append", "store.snapshot", "fault.fail",
+                 "fault.restore", "fault.pick_target"}
+DURABLE_COUNTERS = {"journal.records", "journal.bytes", "journal.fsyncs",
+                    "snapshot.bytes", "fault.migrated", "fault.reprofiled"}
+
+
+def _store_files(path: str) -> dict:
+    """Every file of a store directory by name, as bytes."""
+    out = {}
+    for name in sorted(os.listdir(path)):
+        with open(os.path.join(path, name), "rb") as f:
+            out[name] = f.read()
+    return out
+
+
+def _durable_drive(library, path: str, mark=lambda tag: None) -> dict:
+    """A small durable session, every record fsynced, through a failure
+    and a restore: two jobs decide on device 0, two more are mid-profile
+    there when it fails, so the failure migrates four jobs and cuts two
+    runs.  The store is left as a crash leaves it (neither closed nor
+    flushed), and ``MinosSession.resume`` rebuilds the session from it.
+    ``mark(tag)`` is called after each step; returns what the fleet and
+    the resumed session decided and placed."""
+    from repro.api import SessionStore
+    inventory = DeviceInventory.generate({"tpu-v5e": 3}, VariabilityModel(),
+                                         seed=7)
+    store = SessionStore.create(path, fsync=True, snapshot_every=6)
+    session = MinosSession(library, inventory=inventory, budget_w=20000.0,
+                           store=store, **GATES)
+    dev = inventory[0]
+    streams = [stream_telemetry(s(), 1.0, dev.power_model(), seed=40 + i,
+                                target_duration=0.5, chunk_samples=128,
+                                device_id=dev.device_id)
+               for i, s in enumerate(STREAMS[:4])]
+    handles = session.submit_many([(meta, None) for meta, _ in streams],
+                                  device=dev, chips=2)
+    chunks = [list(c) for _, c in streams]
+    for h, c in zip(handles[:2], chunks[:2]):
+        h.feed(c)
+        h.decision()
+    for h, c in zip(handles[2:], chunks[2:]):
+        h.feed(c[:1])
+    mark("streamed")
+    fleet = session.fleet
+    events = fleet.fail_device(dev.device_id)
+    assert sum(e.kind == "migrate" for e in events) == 4
+    assert sum(e.detail == "reprofile" for e in events) == 2
+    mark("failed")
+    fleet.restore_device(dev.device_id)
+    mark("restored")
+    live = dict(
+        decisions={j: repr(job.decision) for j, job in fleet.jobs.items()},
+        plans={j: repr(job.plan) for j, job in fleet.jobs.items()},
+        schedule=repr(fleet.repacks[-1]))
+    resumed = MinosSession.resume(path, references=library)
+    back = resumed.fleet
+    live["resumed"] = dict(
+        decisions={j: repr(job.decision) for j, job in back.jobs.items()},
+        devices={j: job.device.device_id for j, job in back.jobs.items()})
+    resumed.store.close()
+    mark("resumed")
+    return live
+
+
+def test_durable_spans_and_counters_fire(library, tmp_path):
+    path = str(tmp_path / "store")
+    seen = {}
+
+    def mark(tag):
+        seen[tag] = obs.report()
+
+    obs.enable()
+    _durable_drive(library, path, mark)
+    rep = seen["resumed"]
+    assert DURABLE_SPANS <= set(rep["spans"])
+    assert DURABLE_COUNTERS <= set(rep["counters"])
+    # before the resume: the failure migrated four jobs and cut two runs,
+    # the restore found nothing stranded
+    c = seen["restored"]["counters"]
+    assert c["fault.migrated"] == 4 and c["fault.reprofiled"] == 2
+    assert seen["failed"]["spans"]["fault.fail"]["calls"] == 1
+    assert "fault.restore" not in seen["failed"]["spans"]
+    assert seen["restored"]["spans"]["fault.restore"]["calls"] == 1
+    assert seen["failed"]["spans"]["fault.pick_target"]["calls"] == 4
+    # every record counted, its bytes and its fsync: the journal file
+    # holds exactly what the counters say
+    journal = os.path.join(path, JOURNAL_FILE)
+    records = c["journal.records"]
+    assert rep["spans"]["journal.append"]["calls"] >= records > 0
+    assert c["journal.fsyncs"] == records
+    with open(journal, "rb") as f:
+        lines = f.read().splitlines(keepends=True)
+    assert c["journal.bytes"] == sum(map(len, lines[:records]))
+    # the cadence snapshot and the resume's closing one, with their bytes
+    snaps = [n for n in os.listdir(path) if n.startswith("snapshot-")]
+    assert rep["spans"]["store.snapshot"]["calls"] >= 2
+    assert rep["counters"]["snapshot.bytes"] >= sum(
+        os.path.getsize(os.path.join(path, n)) for n in snaps) > 0
+    for name in DURABLE_SPANS:
+        s = rep["spans"][name]
+        assert 0 <= s["self_s"] <= s["total_s"], name
+
+
+def test_durable_outputs_are_byte_identical_with_tracing_on_and_off(
+        library, tmp_path, monkeypatch):
+    from repro.store import snapshots as snapshots_mod
+    # the wall-clock stamps of records and snapshots are informational;
+    # pin them so that the two stores can be compared byte for byte
+    pinned = types.SimpleNamespace(time=lambda: 0.0)
+    monkeypatch.setattr(journal_mod, "time", pinned)
+    monkeypatch.setattr(snapshots_mod, "time", pinned)
+    off = _durable_drive(library, str(tmp_path / "off"))
+    obs.enable()
+    on = _durable_drive(library, str(tmp_path / "on"))
+    assert obs.report()["counters"]["journal.fsyncs"] > 0
+    assert on == off
+    assert on["resumed"]["decisions"] == on["decisions"]
+    assert _store_files(str(tmp_path / "on")) \
+        == _store_files(str(tmp_path / "off"))
+
+
+def test_durable_off_path_reads_no_clock(library, tmp_path, monkeypatch):
+    def no_clock():
+        raise AssertionError("the recorder read the clock while off")
+
+    monkeypatch.setattr(time, "perf_counter_ns", no_clock)
+    _durable_drive(library, str(tmp_path / "store"))
+    assert obs.report() == {"spans": {}, "counters": {}}

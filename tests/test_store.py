@@ -320,6 +320,32 @@ def test_from_config_store_key(micro_library, tmp_path):
     resumed.close()
 
 
+def test_fleet_property_is_the_journaled_controller(micro_library,
+                                                   tmp_path):
+    """``MinosSession.fleet`` is the session's own controller, read-only,
+    and a mutation made through it is journaled and resumed."""
+    path = str(tmp_path / "fleet")
+    session = MinosSession(micro_library, inventory=_inventory(),
+                           budget_w=20000.0, store=path, **GATES)
+    fleet = session.fleet
+    assert fleet is session._fleet and fleet.journal is session.store
+    with pytest.raises(AttributeError):
+        session.fleet = None
+    handle = session.submit(_telemetry(micro_gemm(), 7), chips=2)
+    handle.run()
+    seq, dev = session.store.journal.last_seq, handle.device.device_id
+    fleet.fail_device(dev)
+    records, _ = EventJournal.recover(os.path.join(path, JOURNAL_FILE))
+    assert records[seq].kind == "fail" and records[seq].data == {
+        "device": dev}
+    assert handle.device.device_id != dev
+    resumed = MinosSession.resume(path, references=micro_library)
+    assert resumed.device_health == session.device_health
+    assert resumed.jobs[handle.job_id].device.device_id \
+        == handle.device.device_id
+    resumed.close()
+
+
 def test_fresh_store_refuses_existing_journal(micro_library, tmp_path):
     path = str(tmp_path / "reused")
     MinosSession(micro_library, store=path, **GATES).close()
