@@ -62,7 +62,7 @@ from repro.pipeline.online import CapDecision, OnlineCapController, \
 from repro.sched.dvfs import SimActuator
 from repro.sched.power_sched import IncrementalPacker, JobPlan, \
     PowerAwareScheduler, RepackStats, ScheduleResult
-from repro.store import kinds
+from repro.store import Encoded, kinds
 
 
 class _PendingRepack:
@@ -448,12 +448,12 @@ class FleetCapController:
 
     def _journal_admit(self, spec: dict) -> None:
         if self.journal is not None:
-            # the record payload (dataclasses.asdict over meta/devices) is
-            # the expensive part — only build it when a store is attached
+            # the record payload (the meta's kernel rows above all) is the
+            # expensive part — only build it when a store is attached
             self._journal(
                 kinds.ADMIT, job_id=spec["job_id"],
                 device=device_record(spec["device"]), chips=spec["chips"],
-                meta=meta_record(spec["meta"]),
+                meta=Encoded(meta_record(spec["meta"])),
                 profile_to_completion=spec["profile_to_completion"],
                 devices=[device_record(d) for d in spec["span"]],
                 mesh=mesh_record(spec["mesh"]),
@@ -673,7 +673,8 @@ class FleetCapController:
             raise ValueError(f"job {job_id!r} already decided; nothing to "
                              f"re-profile")
         meta = meta if meta is not None else job.builder.meta
-        self._journal(kinds.REPROFILE, job_id=job_id, meta=meta_record(meta))
+        self._journal(kinds.REPROFILE, job_id=job_id,
+                      meta=Encoded(meta_record(meta)))
         self._replace_builder(job, meta)
         job.needs_reprofile = False
         self._sync_store()

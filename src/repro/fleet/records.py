@@ -30,8 +30,17 @@ def device_from_record(rec: dict) -> DeviceInstance:
                           spec=ChipSpec(**rec["spec"]))
 
 
+_META_FIELDS = tuple(f.name for f in dataclasses.fields(TraceMeta))
+
+
 def meta_record(meta: TraceMeta) -> dict:
-    return dataclasses.asdict(meta)
+    """The meta's fields as they are, in one flat dict: the tuples of
+    ``kernel_rows`` (hundreds of rows) are neither copied nor walked,
+    since JSON writes them as the arrays ``dataclasses.asdict`` would.
+    A journal takes it pre-encoded (``repro.store.Encoded``)."""
+    rec = {name: getattr(meta, name) for name in _META_FIELDS}
+    rec["kernel_rows"] = list(rec["kernel_rows"])
+    return rec
 
 
 def meta_from_record(rec: dict) -> TraceMeta:

@@ -8,7 +8,8 @@ the materialized state are written on a record-count cadence, and
 intact snapshot plus the journal tail — with zero classifier calls.
 
 This package is deliberately codec-agnostic (no ``repro.api`` imports):
-the session injects its own encoder, and :mod:`repro.store.reports`
+the session injects its own encoder (values already JSON-ready come as
+:class:`Encoded` and skip it), and :mod:`repro.store.reports`
 consumes the raw journal dicts directly.
 """
 from .journal import JOURNAL_FILE, EventJournal, JournalRecord
@@ -16,6 +17,7 @@ from .reports import JournalView, journal_view, store_report, windowed_report
 from .session_store import (
     ROTATE_EVERY,
     SNAPSHOT_EVERY,
+    Encoded,
     NoStoreError,
     SessionStore,
     StoreError,
@@ -27,6 +29,7 @@ __all__ = [
     "ROTATE_EVERY",
     "SNAPSHOT_EVERY",
     "SNAPSHOT_RETAIN",
+    "Encoded",
     "EventJournal",
     "JournalRecord",
     "JournalView",

@@ -54,12 +54,31 @@ import repro.obs as obs
 JOURNAL_FILE = "journal.jsonl"
 
 _CANONICAL = dict(sort_keys=True, separators=(",", ":"), allow_nan=False)
+_ENCODER = json.JSONEncoder(**_CANONICAL)
 
 
 def _checksum(seq: int, ts: float, kind: str, data) -> str:
     body = json.dumps({"seq": seq, "ts": ts, "kind": kind, "data": data},
                       **_CANONICAL)
     return hashlib.sha256(body.encode()).hexdigest()
+
+
+def _encode_line(seq: int, ts: float, kind: str, data) -> str:
+    """The journal line of one record, ``data`` serialized once.
+
+    The checksum body ``{"data":D,"kind":K,"seq":S,"ts":T}`` and the line
+    ``{"data":D,"kind":K,"seq":S,"sha":H,"ts":T}`` are what canonical
+    (sorted-key, compact) dumps of the two dicts give; both are spliced
+    from the one text ``D``, and ``K``, ``S`` and ``T`` are each dumped
+    by the same encoder, so the bytes equal the two-dump encoding and
+    :func:`_checksum` verifies them.  Raises ``ValueError``/``TypeError``
+    where ``data`` is not strict JSON, before anything is written."""
+    d = _ENCODER.encode(data)
+    head = (f'{{"data":{d},"kind":{_ENCODER.encode(kind)},'
+            f'"seq":{_ENCODER.encode(seq)},')
+    end = f'"ts":{_ENCODER.encode(ts)}}}'
+    sha = hashlib.sha256((head + end).encode()).hexdigest()
+    return f'{head}"sha":"{sha}",{end}\n'
 
 
 def _base_checksum(base_seq: int, through_segment: int, open_record) -> str:
@@ -202,6 +221,10 @@ class EventJournal:
         per tick; recovery already tolerates a torn batched tail exactly
         like any torn record.
 
+        ``data`` is serialized once (:func:`_encode_line`); data that is
+        not strict JSON raises ``ValueError``/``TypeError`` and leaves the
+        journal as it was.
+
         Recorded (``repro.obs``) as the span ``journal.append``, checksum,
         encoding, write, flush and fsync included, and the counters
         ``journal.records``, ``journal.bytes`` and ``journal.fsyncs``."""
@@ -210,9 +233,7 @@ class EventJournal:
             # ts is informational wall-clock metadata, never replayed into
             # session state; deterministic callers pin it via the parameter
             ts = time.time() if ts is None else float(ts)  # minoslint: disable=W301
-            rec = {"seq": seq, "ts": ts, "kind": str(kind), "data": data}
-            rec["sha"] = _checksum(seq, ts, rec["kind"], data)
-            line = json.dumps(rec, **_CANONICAL) + "\n"
+            line = _encode_line(seq, ts, str(kind), data)
             fh = self._handle()
             fh.write(line)
             if self._batch_depth and not self.fsync:

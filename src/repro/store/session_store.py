@@ -11,7 +11,9 @@ Layout::
 The store is codec-agnostic: callers hand it an ``encode`` callable (the
 session passes ``repro.api.results.to_dict``) so ``repro.store`` never
 imports ``repro.api`` — payloads are encoded to JSON-ready dicts at record
-time and handed back verbatim on recovery.
+time and handed back verbatim on recovery.  A value its producer already
+built in JSON-ready form comes wrapped in :class:`Encoded` and skips that
+walk.
 
 Snapshot cadence is record-count based (``snapshot_every``).  Writing a
 snapshot synchronously inside :meth:`record` would capture state *before*
@@ -44,6 +46,19 @@ class NoStoreError(StoreError):
 
 def _identity(obj):
     return obj
+
+
+class Encoded:
+    """A record payload value already in JSON-ready form (string keys,
+    finite floats): :meth:`SessionStore.record` hands it to the journal
+    as it is, without the store's ``encode`` walk.  Where the journal
+    finds it not strict JSON (a non-finite float, a NumPy scalar JSON
+    cannot write, an unknown type), the record takes ``encode`` over the
+    same value, so its line is the one the walk alone would write."""
+    __slots__ = ("value",)
+
+    def __init__(self, value):
+        self.value = value
 
 
 class SessionStore:
@@ -165,9 +180,28 @@ class SessionStore:
     # -- writing ---------------------------------------------------------
     def record(self, kind: str, **data) -> int:
         """Journal one event (write-ahead: call BEFORE applying the
-        mutation).  Payload values pass through ``encode``."""
-        seq = self.journal.append(kind, {k: self.encode(v)
-                                         for k, v in data.items()})
+        mutation).  Payload values pass through ``encode``, but for
+        :class:`Encoded` ones, which fall back to it only where the
+        journal refuses them.  Recorded (``repro.obs``) as the span
+        ``store.encode`` around the encoding, and the counter
+        ``journal.encode_fallbacks``: ``Encoded`` values re-encoded."""
+        encode = self.encode
+        with obs.span("store.encode"):
+            payload = {k: v.value if type(v) is Encoded else encode(v)
+                       for k, v in data.items()}
+        try:
+            seq = self.journal.append(kind, payload)
+            fallbacks = 0
+        except (ValueError, TypeError):
+            ready = [k for k, v in data.items() if type(v) is Encoded]
+            if not ready:
+                raise
+            with obs.span("store.encode"):
+                for k in ready:
+                    payload[k] = encode(payload[k])
+            seq = self.journal.append(kind, payload)
+            fallbacks = len(ready)
+        obs.count("journal.encode_fallbacks", fallbacks)
         self._since_snapshot += 1
         self._since_compact += 1
         if self._since_snapshot >= self.snapshot_every:

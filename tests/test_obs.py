@@ -252,10 +252,11 @@ def test_pq_rebuilds_count_windows_rebuilt(shape):
 
 
 # spans and counters of the durable and fault paths
-DURABLE_SPANS = {"journal.append", "store.snapshot", "fault.fail",
-                 "fault.restore", "fault.pick_target"}
+DURABLE_SPANS = {"journal.append", "store.encode", "store.snapshot",
+                 "fault.fail", "fault.restore", "fault.pick_target"}
 DURABLE_COUNTERS = {"journal.records", "journal.bytes", "journal.fsyncs",
-                    "snapshot.bytes", "fault.migrated", "fault.reprofiled"}
+                    "journal.encode_fallbacks", "snapshot.bytes",
+                    "fault.migrated", "fault.reprofiled"}
 
 
 def _store_files(path: str) -> dict:
@@ -342,6 +343,9 @@ def test_durable_spans_and_counters_fire(library, tmp_path):
     records = c["journal.records"]
     assert rep["spans"]["journal.append"]["calls"] >= records > 0
     assert c["journal.fsyncs"] == records
+    # each record's payload encoded once, every admit meta on the flat path
+    assert rep["spans"]["store.encode"]["calls"] >= records
+    assert c["journal.encode_fallbacks"] == 0
     with open(journal, "rb") as f:
         lines = f.read().splitlines(keepends=True)
     assert c["journal.bytes"] == sum(map(len, lines[:records]))
@@ -379,4 +383,35 @@ def test_durable_off_path_reads_no_clock(library, tmp_path, monkeypatch):
 
     monkeypatch.setattr(time, "perf_counter_ns", no_clock)
     _durable_drive(library, str(tmp_path / "store"))
+    assert obs.report() == {"spans": {}, "counters": {}}
+
+
+def test_store_encoding_span_and_fallback_counter(tmp_path, monkeypatch):
+    """On, every record opens ``store.encode`` and counts the pre-encoded
+    values that took the generic walk; off, the same records create
+    neither and allocate no span."""
+    from repro.api import SessionStore, to_dict
+    from repro.store import Encoded
+
+    def records(path):
+        store = SessionStore.create(path, encode=to_dict)
+        store.record("budget", budget_w=float("inf"))
+        store.record("reprofile", meta=Encoded({"rows": [(0.5, 1.0)]}))
+        store.record("reprofile", meta=Encoded({"rows": [(float("nan"),)]}))
+        store.close()
+
+    obs.enable()
+    records(str(tmp_path / "on"))
+    rep = obs.report()
+    assert rep["spans"]["store.encode"]["calls"] == 4     # one re-walk
+    assert rep["counters"]["journal.encode_fallbacks"] == 1
+    assert rep["counters"]["journal.records"] == 3
+    obs.disable()
+    obs.reset()
+
+    def no_span(name):
+        raise AssertionError(f"span {name!r} allocated while off")
+
+    monkeypatch.setattr(obs, "_Span", no_span)
+    records(str(tmp_path / "off"))
     assert obs.report() == {"spans": {}, "counters": {}}

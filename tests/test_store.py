@@ -14,6 +14,7 @@ The contracts pinned here:
   * the satellite hardening: poisoned telemetry cannot corrupt a later
     snapshot, and a corrupt spike cache degrades to a cold rebuild.
 """
+import dataclasses
 import glob
 import json
 import math
@@ -34,6 +35,11 @@ from repro.api import (DeviceInventory, EventJournal, MinosSession,
                        micro_spmv_memory, micro_stencil, store_report,
                        stream_profile_workload, stream_telemetry, to_dict,
                        windowed_report)
+import repro.obs as obs
+from repro.configs.base import MeshConfig
+from repro.fleet.records import meta_record
+from repro.store import Encoded
+from repro.store import journal as journal_mod
 from repro.store.journal import JOURNAL_FILE
 from repro.telemetry.simulator import TelemetryChunk
 
@@ -592,6 +598,138 @@ def test_meta_roundtrip_preserves_traces():
     rebuilt = meta_from_record(json.loads(json.dumps(meta_record(meta))))
     assert rebuilt == meta
     assert isinstance(rebuilt, TraceMeta)
+
+
+# ---------------------------------------------------------------------------
+# one-pass record encoding: the journal's bytes are those of the two-pass
+# encoder it replaced
+# ---------------------------------------------------------------------------
+def _two_pass_line(seq, ts, kind, data) -> str:
+    """A journal line as the store and journal wrote it before the
+    one-pass encoding: every payload value walked by ``to_dict`` (a
+    ``TraceMeta`` first deep-copied by ``dataclasses.asdict``), then the
+    payload serialized once for the checksum and again for the line."""
+    payload = {k: to_dict(dataclasses.asdict(v) if isinstance(v, TraceMeta)
+                          else v)
+               for k, v in data.items()}
+    rec = {"seq": seq, "ts": ts, "kind": kind, "data": payload}
+    rec["sha"] = journal_mod._checksum(seq, ts, kind, payload)
+    return json.dumps(rec, sort_keys=True, separators=(",", ":"),
+                      allow_nan=False) + "\n"
+
+
+def _odd_meta() -> TraceMeta:
+    """A meta the flat codec cannot prove identical: a NumPy float among
+    the fields, a NaN among the kernel rows."""
+    meta, _ = _telemetry(micro_stencil(), 9)
+    rows = list(meta.kernel_rows)
+    rows[0] = (rows[0][0], math.nan, rows[0][2])
+    return dataclasses.replace(meta, exec_time=np.float64(meta.exec_time),
+                               app_sm_util=np.float32(0.25),
+                               kernel_rows=rows)
+
+
+@pytest.fixture(scope="module")
+def encoded_records(micro_library, tmp_path_factory):
+    """A durable session through one of each record payload: an admit
+    spanning two devices with a mesh, a decision with its plan, the
+    failure's events, an unbounded budget, and, through the store, a
+    reprofile whose meta must take the generic walk.  Returns
+    ``(journal_path, {case: (seq, kind, reference payload)},
+    fallbacks)``."""
+    path = str(tmp_path_factory.mktemp("encode") / "session")
+    inventory = _inventory()
+    session = MinosSession(micro_library, inventory=inventory,
+                           budget_w=20000.0, store=path, **GATES)
+    store = session.store
+    calls = []
+    real_record = store.record
+
+    def spy(kind, **data):
+        seq = real_record(kind, **data)
+        calls.append((seq, kind, data))
+        return seq
+
+    store.record = spy
+    tele = _telemetry(micro_spmv_memory(), 100)
+    span = (inventory[0], inventory[1])
+    a = session.submit(tele, device=span[0], devices=span, chips=4,
+                       mesh=MeshConfig((2, 2), ("data", "model")),
+                       global_batch=64)
+    a.run()
+    session.fail_device(a.device.device_id)
+    session.set_budget(math.inf)
+    obs.reset()
+    obs.enable()
+    try:
+        store.record("reprofile", job_id=a.job_id,
+                     meta=Encoded(meta_record(_odd_meta())))
+        fallbacks = obs.report()["counters"]["journal.encode_fallbacks"]
+    finally:
+        obs.disable()
+        obs.reset()
+    store.close()
+
+    def first(kind):
+        return next((seq, k, d) for seq, k, d in calls if k == kind)
+
+    seq, kind, data = first("admit")
+    assert isinstance(data["meta"], Encoded)
+    cases = {"admit": (seq, kind, dict(data, meta=tele[0])),
+             "decision": first("decision"), "event": first("event"),
+             "budget_inf": first("budget"),
+             "meta_nan_numpy": calls[-1][:2] + (
+                 dict(calls[-1][2], meta=_odd_meta()),)}
+    return os.path.join(path, JOURNAL_FILE), cases, fallbacks
+
+
+@pytest.mark.parametrize("case", ["admit", "decision", "event",
+                                  "budget_inf", "meta_nan_numpy"])
+def test_journal_lines_match_the_two_pass_encoder(encoded_records, case):
+    jp, cases, _ = encoded_records
+    seq, kind, reference = cases[case]
+    with open(jp, "rb") as f:
+        line = f.read().decode().splitlines(keepends=True)[seq - 1]
+    ts = json.loads(line)["ts"]
+    assert line == _two_pass_line(seq, ts, kind, reference)
+    if case == "admit":
+        # a real multi-row meta, on the flat path: nothing fell back
+        assert len(reference["meta"].kernel_rows) > 1
+        assert reference["mesh"] is not None
+    if case == "budget_inf":
+        assert '"budget_w":{"__float__":"inf"}' in line
+
+
+def test_one_pass_journal_recovers_every_line(encoded_records):
+    """The odd meta took the generic walk, counted once; the recovery's
+    checksum verifier accepts every line the one-pass encoder wrote."""
+    jp, cases, fallbacks = encoded_records
+    assert fallbacks == 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        records, good = EventJournal.recover(jp)
+    assert good == os.path.getsize(jp)
+    with open(jp, "rb") as f:
+        assert len(records) == len(f.read().splitlines())
+    assert records[-1].kind == "reprofile"
+    assert records[-1].data["meta"]["kernel_rows"][0][1] \
+        == {"__float__": "nan"}
+    assert {r.kind for r in records} >= {"admit", "decision", "event",
+                                         "budget", "fail", "reprofile"}
+
+
+def test_encoded_value_that_is_not_json_raises_without_an_encoder(tmp_path):
+    """A store with no codec cannot walk a refused value: the record
+    raises and the journal stays as it was."""
+    store = SessionStore.create(str(tmp_path / "s"))
+    store.record("open", a=1)
+    with pytest.raises(ValueError):
+        store.record("reprofile", meta=Encoded({"x": math.nan}))
+    assert store.journal.last_seq == 1
+    store.record("tick", i=0)
+    store.close()
+    records, _ = EventJournal.recover(store.journal.path)
+    assert [r.seq for r in records] == [1, 2]
 
 
 def test_journal_batch_coalesces_flushes_and_recovers(tmp_path):
