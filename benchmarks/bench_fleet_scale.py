@@ -6,9 +6,9 @@ The PR 3 fleet loop stepped jobs one at a time through per-job
 fleets run thousands of concurrent jobs (arXiv:2502.18680).  This bench
 admits a serving-weighted 10k-job mix onto a zero-variability three-
 generation inventory and drains the multiplexed feed through
-``FleetCapController(engine="batched", repack="tick")``: every mux tick
-advances all live jobs in one ``BatchProfileEngine.ingest_batch`` columnar
-pass, and all of a tick's decisions share one re-pack.
+``FleetCapController``: every mux tick advances all live jobs in one
+``BatchProfileEngine.ingest_batch`` columnar pass, and every landed
+decision re-packs incrementally.
 
 Telemetry is pre-generated once per distinct (workload, chip model) pair
 and shared across jobs — chunks are immutable, so 10k builders can read the
@@ -159,8 +159,7 @@ def run(smoke: bool = False) -> dict:
     best = None
     for _ in range(attempts):
         fleet = FleetCapController(lib, budget_w=budget,
-                                   provision_quantile="p99", repack="tick",
-                                   **GATES)
+                                   provision_quantile="p99", **GATES)
         mux = FleetTelemetryMux()
         t0 = time.perf_counter()
         # bulk admission: the whole fleet lands through ONE validated call
@@ -236,8 +235,6 @@ def run(smoke: bool = False) -> dict:
             "chunk_samples": 256,
             "budget_w": round(budget, 1),
             "budget_fraction_of_nameplate": BUDGET_FRACTION,
-            "engine": "batched",
-            "repack": "tick",
             "attempts": attempts,
         },
         "jobs_per_s": round(jobs_per_s, 1),

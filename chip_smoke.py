@@ -15,9 +15,10 @@ counted on the device:
     to every decision after the engine's shape buckets are warmed up.
 
 The same deployment then runs on the host through the plain references —
-one ``ProfileBuilder`` per job (``FleetCapController(engine="perjob")``)
-and ``PowerAwareScheduler.pack`` — and every job's cap and the final placed
-and deferred lists must match.  Re-simulating the placed jobs at their caps
+``OnlineCapController.run`` per job (one ``ProfileBuilder`` each) at its
+device's effective TDP, then one ``PowerAwareScheduler.pack`` over the
+plans — and every job's cap and the final placed and deferred lists must
+match.  Re-simulating the placed jobs at their caps
 must show zero sustained budget violations.
 
 Prints one line per phase, then one JSON object as the last line.  Exits
@@ -123,9 +124,9 @@ def drive(n_jobs: int = N_JOBS, fleet=FLEET, store: str = STORE,
     """Run the deployment through the session and through the references;
     returns every phase's figures.  ``compiles`` (a ``CompileCounter``)
     splits compiles into warm-up and drive; without one both read 0."""
-    from repro.api import (DeviceInventory, FleetCapController,
-                           FleetTelemetryMux, MinosSession, ReferenceLibrary,
-                           TPUPowerModel, VariabilityModel,
+    from repro.api import (DeviceInventory, FleetTelemetryMux, MinosSession,
+                           OnlineCapController, PowerAwareScheduler,
+                           ReferenceLibrary, TPUPowerModel, VariabilityModel,
                            build_reference_library, fleet_job_mix,
                            stream_telemetry)
     out: dict = {}
@@ -192,29 +193,25 @@ def drive(n_jobs: int = N_JOBS, fleet=FLEET, store: str = STORE,
     out["budget"] = dict(violations=n_viol, peak_sustained_w=peak,
                          budget_w=budget)
 
-    # the plain references on the host: one ProfileBuilder per job, one
-    # full first-fit-decreasing pack at the end
+    # the plain references on the host: one ProfileBuilder per job, run to
+    # its first confident decision, and one full first-fit-decreasing pack
+    # over the plans at the end
     t0 = time.perf_counter()
-    ref = FleetCapController(
-        ReferenceLibrary.load(store), budget_w=budget,
-        provision_quantile="p99", engine="perjob", repack="tick",
-        packer="full", **GATES)
-    ref.admit_many(dict(device=dev, meta=src[0], chips=chips, job_id=jid)
-                   for (_, chips, dev), src, jid
-                   in zip(assigned, sources, job_ids))
+    clf = ReferenceLibrary.load(store).classifier()
+    sched = PowerAwareScheduler(clf, tdp_w=0.0, quantile="p99")
+    ref_caps, plans = {}, []
     mux = FleetTelemetryMux()
-    for (_, _, dev), (meta, chunks), jid in zip(assigned, sources, job_ids):
+    for (_, chips, dev), (meta, chunks), jid in zip(assigned, sources,
+                                                    job_ids):
+        d = OnlineCapController(clf, **GATES).run(meta, chunks,
+                                                  dev.effective_tdp_w)
+        ref_caps[jid] = d.cap
+        plans.append(sched.plan_from_selection(d.selection, chips, dev,
+                                               job_id=jid))
         mux.add_job(jid, meta, chunks, device_id=dev.device_id)
-    ticks = 0
-    for batch in mux.ticks():
-        ref.ingest_tick(batch)
-        ticks += 1
-    ref.finalize()
-    ref_pack = ref.scheduler.pack(
-        (j.plan for j in ref.jobs.values() if j.plan is not None), budget)
+    ref_pack = sched.pack(plans, budget)
+    ticks = sum(1 for _ in mux.ticks())
     caps = {jid: d.cap for jid, d in report.decisions.items()}
-    ref_caps = {jid: job.decision.cap for jid, job in ref.jobs.items()
-                if job.decision is not None}
     cap_diff = [(jid, caps.get(jid), ref_caps.get(jid)) for jid in job_ids
                 if caps.get(jid) != ref_caps.get(jid)]
     placed_diff = _mismatches([(p.job_id, p.cap) for p in schedule.placed],

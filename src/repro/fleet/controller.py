@@ -1,9 +1,9 @@
 """Cluster-wide online capping under a shared power budget.
 
-``FleetCapController`` scales the PR 2 single-job pipeline to a
-heterogeneous fleet: every admitted job gets its own ``ProfileBuilder`` and
-``OnlineCapController`` (sharing one warm classifier), fed from the
-``FleetTelemetryMux``'s interleaved chunk feed.  The moment any job's
+``FleetCapController`` scales the single-job pipeline to a heterogeneous
+fleet: every admitted job gets one slot of a shared ``BatchProfileEngine``
+and its own ``OnlineCapController`` (sharing one warm classifier), fed from
+the ``FleetTelemetryMux``'s interleaved chunk feed.  The moment any job's
 confidence gate clears, its cap is actuated on its device and the whole pod
 is re-packed through the heterogeneity-aware ``PowerAwareScheduler`` against
 the shared cluster budget — the POLCA-style early-re-provisioning loop, now
@@ -54,8 +54,7 @@ from repro.fleet.mux import FleetChunk, FleetTelemetryMux
 from repro.fleet.records import device_record, meta_record, mesh_record
 from repro.ft.elastic import plan_new_mesh, rescale_batch
 from repro.ft.fleetwatch import FleetStragglerAdapter
-from repro.pipeline.batch import BatchProfileEngine
-from repro.pipeline.builder import ProfileBuilder
+from repro.pipeline.batch import BatchProfileEngine, SlotBuilder
 from repro.pipeline.library import ReferenceLibrary
 from repro.pipeline.online import CapDecision, OnlineCapController, \
     finalize_fleet, observe_fleet
@@ -142,7 +141,7 @@ class FleetJob:
     job_id: str
     device: DeviceInstance         # primary device (profiling frame)
     chips: int
-    builder: object                # ProfileBuilder | pipeline.batch.SlotBuilder
+    builder: SlotBuilder           # the job's slot of the fleet's engine
     controller: OnlineCapController
     actuator: object               # FrequencyActuator | None (plugin-chosen)
     decision: CapDecision | None = None
@@ -176,6 +175,13 @@ class FleetResult:
 class FleetCapController:
     """Run one ``OnlineCapController`` per job under a shared power budget.
 
+    Every job's profiling state is one slot of the controller's
+    ``BatchProfileEngine`` (``self.engine``); each tick advances all of its
+    jobs in one columnar pass and one classification sweep, and every
+    landed decision re-packs the fleet through an ``IncrementalPacker``
+    (full ``PowerAwareScheduler.pack`` sweeps only once a plan cannot be
+    held exactly).
+
     ``references`` is a ``ReferenceLibrary`` (preferred: warm classifier) or
     a prebuilt ``MinosClassifier`` — shared by every job.  Gate thresholds
     (``min_confidence`` etc.) are forwarded verbatim to each per-job
@@ -197,23 +203,15 @@ class FleetCapController:
                  actuator_factory=SimActuator.for_device,
                  inventory: DeviceInventory | None = None,
                  straggler_adapter: FleetStragglerAdapter | None = None,
-                 journal=None, engine: str = "batched",
-                 repack: str = "decision", packer: str = "incremental"):
-        """``engine`` selects the builder state layout: ``"batched"``
-        (default) backs every job by one slot of a shared columnar
-        ``BatchProfileEngine`` — bit-identical to ``"perjob"`` (one
-        ``ProfileBuilder`` per job, the reference path), but advanced in one
-        stacked pass per ``ingest_tick``.  ``repack`` sets the re-packing
-        cadence: ``"decision"`` (default) re-packs on every landed decision
-        exactly like the per-chunk path; ``"tick"`` coalesces to one re-pack
-        per mux tick — same final packing, O(ticks) instead of O(decisions)
-        scheduler calls, the fleet-scale mode.  ``packer`` selects how each
-        re-pack is computed: ``"incremental"`` (default) maintains the
-        decided plans in an ``IncrementalPacker`` so every plan mutation
-        updates only the affected tail of the first-fit pass —
-        byte-identical results to ``"full"`` (one ``PowerAwareScheduler.
-        pack`` sweep per re-pack, the hypothesis-pinned reference) at
-        O(block + n/block) per event instead of O(n log n)."""
+                 journal=None):
+        """Builder state lives in one shared ``BatchProfileEngine``: each
+        job's slot is bit-identical to a standalone ``ProfileBuilder`` fed
+        the same chunks.  Decided plans live in an ``IncrementalPacker``, so
+        a plan mutation updates only the affected tail of the first-fit
+        pass — byte-identical to one ``PowerAwareScheduler.pack`` sweep per
+        re-pack at O(block + n/block) per event instead of O(n log n).  A
+        plan the packer cannot hold exactly (non-finite power, colliding
+        identity) drops the controller to full sweeps for good."""
         if isinstance(references, ReferenceLibrary):
             self.clf = references.classifier()
         elif isinstance(references, MinosClassifier):
@@ -233,20 +231,8 @@ class FleetCapController:
         self.scheduler = PowerAwareScheduler(
             self.clf, tdp_w=0.0, objective=objective,
             quantile=provision_quantile)
-        if engine not in ("batched", "perjob"):
-            raise ValueError(f"engine must be 'batched' or 'perjob', "
-                             f"got {engine!r}")
-        if repack not in ("decision", "tick"):
-            raise ValueError(f"repack must be 'decision' or 'tick', "
-                             f"got {repack!r}")
-        if packer not in ("incremental", "full"):
-            raise ValueError(f"packer must be 'incremental' or 'full', "
-                             f"got {packer!r}")
-        self.engine = BatchProfileEngine() if engine == "batched" else None
-        self.repack_mode = repack
-        self.packer_mode = packer
-        self._packer = self.scheduler.packer(self.budget_w) \
-            if packer == "incremental" else None
+        self.engine = BatchProfileEngine()
+        self._packer = self.scheduler.packer(self.budget_w)
         self.repack_s = 0.0          # wall-clock spent maintaining packings
         self.inventory = inventory
         self.straggler_adapter = straggler_adapter
@@ -336,29 +322,14 @@ class FleetCapController:
         return clf
 
     # -- builder lifecycle -----------------------------------------------
-    def _make_builder(self, meta, tdp: float):
-        """One profiling-state handle in the configured engine: a slot view
-        of the shared columnar engine, or a standalone ``ProfileBuilder``."""
-        if self.engine is not None:
-            return self.engine.builder(meta, tdp)
-        return ProfileBuilder(meta, tdp=tdp)
-
-    @staticmethod
-    def _drop_builder(builder) -> None:
-        """Release a builder's engine slot for reuse (no-op for the
-        standalone ``ProfileBuilder``)."""
-        release = getattr(builder, "release", None)
-        if release is not None:
-            release()
-
     def _replace_builder(self, job: FleetJob, meta=None,
                          tdp: float | None = None):
         """Swap a job's profiling state for a fresh run (migration /
         reprofile), freeing the old engine slot."""
         meta = meta if meta is not None else job.builder.meta
         tdp = job.device.effective_tdp_w if tdp is None else tdp
-        self._drop_builder(job.builder)
-        job.builder = self._make_builder(meta, tdp)
+        job.builder.release()
+        job.builder = self.engine.builder(meta, tdp)
         return job.builder
 
     # -- admission -------------------------------------------------------
@@ -470,8 +441,8 @@ class FleetCapController:
             controller.quarantine_tap = self._quarantine_tap
         self.jobs[spec["job_id"]] = FleetJob(
             job_id=spec["job_id"], device=device, chips=spec["chips"],
-            builder=self._make_builder(spec["meta"],
-                                       device.effective_tdp_w),
+            builder=self.engine.builder(spec["meta"],
+                                        device.effective_tdp_w),
             controller=controller, actuator=actuator,
             profile_to_completion=spec["profile_to_completion"],
             devices=spec["span"], mesh=spec["mesh"],
@@ -479,143 +450,118 @@ class FleetCapController:
 
     # -- streaming -------------------------------------------------------
     def ingest(self, fchunk: FleetChunk) -> CapDecision | None:
-        """Route one multiplexed chunk to its job.  Returns that job's
-        ``CapDecision`` when this chunk tips its confidence gate (which also
-        re-packs the fleet); ``None`` otherwise.
+        """Route one multiplexed chunk to its job: ``ingest_tick([fchunk])``
+        without the ``tick`` span.  Returns that job's ``CapDecision`` when
+        this chunk tips its confidence gate (which also re-packs the
+        fleet); ``None`` otherwise."""
+        decisions = self._ingest_tick((fchunk,))
+        return decisions[0] if decisions else None
 
-        Telemetry from a failed device (in flight when the failure landed)
-        is discarded, as is telemetry for a job that has left the fleet or
-        that awaits its re-run after a mid-profile migration (the chunk
-        belongs to the run that died; ``restart_profile`` starts the new
-        one) — the wire keeps no promises under churn.  With a straggler adapter
-        attached, every chunk also feeds the per-device cadence monitor and
-        flagged devices are degraded-and-drained automatically."""
-        if self.straggler_adapter is not None:
-            self.straggler_adapter.observe(fchunk)
-            if self.straggler_adapter.should_check():
-                self._auto_degrade()
-        if fchunk.device_id in self._failed_devices:
-            self._dropped += 1
-            return None
-        job = self.jobs.get(fchunk.job_id)
-        if job is None or job.needs_reprofile:
-            self._dropped += 1             # retired, or a dead run's chunk
-            return None
-        return self.ingest_chunk(fchunk.job_id, fchunk.chunk)
-
-    def ingest_chunk(self, job_id: str, chunk,
-                     _defer_repack: bool = False) -> CapDecision | None:
+    def ingest_chunk(self, job_id: str, chunk) -> CapDecision | None:
         """Un-muxed entry point: ingest one raw ``TelemetryChunk`` for
-        ``job_id`` (the ``MinosSession``/``JobHandle`` feed path)."""
+        ``job_id`` (the ``MinosSession``/``JobHandle`` feed path).  Without
+        a device tag there is no failed-device filter; a decided job's
+        chunk is dropped unless it profiles to completion, and a mid-profile
+        migrant raises until its run restarts."""
         job = self.jobs[job_id]
         if job.decision is not None:
             if not job.profile_to_completion:
                 self._dropped += 1
                 return None        # profiling already stopped for this job
-            job.builder.ingest(chunk)
-            return None            # decision already made; just keep building
-        if job.needs_reprofile:
+        elif job.needs_reprofile:
             # the partial trace died with the job's old device; without a
             # device tag on this path we cannot tell the stale stream from
             # the re-run, so demand an explicit restart
             raise ValueError(
                 f"job {job_id!r} migrated mid-profile; restart its run via "
                 f"restart_profile()/JobHandle.reprofile() before feeding")
-        job.builder.ingest(chunk)
-        decision = job.controller.observe(job.builder)
-        if decision is None:
-            return None
-        self._decide(job, decision)
-        if not _defer_repack:
-            self._repack()
-            self._sync_store()
-        return decision
+        decisions = self._advance([(job, chunk)])
+        return decisions[0] if decisions else None
 
     def ingest_tick(self, batch) -> list[CapDecision]:
-        """Advance the fleet by one mux tick — a batch of simultaneous
-        ``FleetChunk``s from ``FleetTelemetryMux.ticks()`` — in one columnar
-        engine pass instead of a per-job Python loop.  Returns the decisions
-        that landed this tick, in chunk order.
+        """Advance the fleet by one mux tick — a batch of ``FleetChunk``s
+        from ``FleetTelemetryMux.ticks()`` — and return the decisions that
+        landed, in chunk order.
 
-        Outcome-equivalent to calling ``ingest`` per chunk in batch order:
-        undecided jobs' chunks advance through ``BatchProfileEngine.
-        ingest_batch`` (bit-identical builder state), then confidence gates
-        are observed in the same chunk order, so decisions, journal records,
-        and (with ``repack="decision"``) re-packs land in the identical
-        sequence.  With ``repack="tick"`` all of a tick's decisions share
-        one closing re-pack.  Falls back to the sequential path per chunk
-        when the chunk can't batch (per-job engine, duplicate job in one
-        batch, straggler cadence monitoring — which is order-sensitive)."""
+        The batch is walked in wire order.  A chunk is dropped when its
+        device failed, when its job left the fleet or awaits its re-run
+        after a mid-profile migration (the chunk belongs to the run that
+        died; ``restart_profile`` starts the new one), or when its job has
+        decided and does not profile to completion — the wire keeps no
+        promises under churn.  The rest advance in runs of distinct jobs,
+        one engine pass and one classification sweep per run (``_advance``);
+        a run ends before a chunk whose job it already holds.  With a
+        straggler adapter attached, each chunk feeds the per-device cadence
+        monitor as the walk reaches it, and a device the monitor flags is
+        degraded-and-drained right there, after the chunks before it have
+        landed.  Decisions, events, re-packs and journal records are those
+        of feeding the chunks one at a time."""
         with obs.span("tick"):
             return self._ingest_tick(batch)
 
     def _ingest_tick(self, batch) -> list[CapDecision]:
-        if self.straggler_adapter is not None:
-            # cadence monitoring consumes chunks one at a time in wire
-            # order; keep that path byte-identical
-            return [d for d in (self.ingest(fc) for fc in batch)
-                    if d is not None]
-        defer = self.repack_mode == "tick"
+        adapter = self.straggler_adapter
         store_ctx = self.journal.batch() if self.journal is not None \
             else nullcontext()
         decisions: list[CapDecision] = []
         with store_ctx:
-            # route: engine-eligible chunks batch; the rest go sequential
-            rows = []               # (fchunk, job | None, batched, observe)
-            seen: set[str] = set()
-            slots, chunks = [], []
+            rows: list = []                   # (job, chunk), distinct jobs
+            held: set[str] = set()
             jobs_get = self.jobs.get          # hoisted: this loop runs once
             failed = self._failed_devices     # per chunk at fleet scale
-            eng = self.engine
             for fc in batch:
+                if adapter is not None:
+                    adapter.observe(fc)
+                    flagged = self._flagged_devices() \
+                        if adapter.should_check() else ()
+                    if flagged:
+                        decisions += self._advance(rows)
+                        rows, held = [], set()
+                        for device_id in flagged:
+                            self.degrade_device(device_id)
+                if fc.job_id in held:
+                    # land the run before filtering: it may decide this
+                    # chunk's job, which then drops the chunk
+                    decisions += self._advance(rows)
+                    rows, held = [], set()
                 if fc.device_id in failed:
                     self._dropped += 1
                     continue
                 job = jobs_get(fc.job_id)
-                if job is None or job.needs_reprofile:
-                    self._dropped += 1     # retired, or a dead run's chunk
+                if job is None or job.needs_reprofile \
+                        or (job.decision is not None
+                            and not job.profile_to_completion):
+                    # retired, a dead run's chunk, or profiling stopped
+                    self._dropped += 1
                     continue
-                eligible = (eng is not None
-                            and fc.job_id not in seen
-                            and getattr(job.builder, "engine", None) is eng
-                            and (job.decision is None
-                                 or job.profile_to_completion))
-                seen.add(fc.job_id)
-                if eligible:
-                    slots.append(job.builder.slot)
-                    chunks.append(fc.chunk)
-                    rows.append((fc, job, True, job.decision is None))
-                else:
-                    rows.append((fc, job, False, False))
-            if slots:
-                self.engine.ingest_batch(slots, chunks)
-            # one classification sweep for every gate-passing undecided job
-            # this tick (engine rows only mutate through ingest_batch above,
-            # so the batched observations see exactly the state the per-row
-            # observe calls would)
-            obs = [pos for pos, (_, job, batched, observe) in enumerate(rows)
-                   if batched and observe]
-            tick_ds = dict(zip(obs, observe_fleet(
-                [(rows[pos][1].controller, rows[pos][1].builder)
-                 for pos in obs]))) if obs else {}
-            for pos, (fc, job, batched, observe) in enumerate(rows):
-                if not batched:
-                    d = self.ingest_chunk(fc.job_id, fc.chunk,
-                                          _defer_repack=defer)
-                elif observe:
-                    d = tick_ds.get(pos)
-                    if d is not None:
-                        self._decide(job, d)
-                        if not defer:
-                            self._repack()
-                            self._sync_store()
-                else:
-                    d = None       # decided profile-to-completion job
-                if d is not None:
-                    decisions.append(d)
-            if defer and decisions:
+                held.add(fc.job_id)
+                rows.append((job, fc.chunk))
+            decisions += self._advance(rows)
+        return decisions
+
+    def _advance(self, rows) -> list[CapDecision]:
+        """Advance distinct jobs by one chunk each: one engine pass, one
+        classification sweep over the undecided ones, then every landed
+        decision is pinned, re-packed and synced to the store in row order
+        — the one place an ingest entry lands a decision.  The engine's
+        ``ingest_batch`` and the module's ``observe_fleet`` are looked up
+        per call, so a wrapper installed on either sees every call."""
+        if not rows:
+            return []
+        self.engine.ingest_batch([job.builder.slot for job, _ in rows],
+                                 [chunk for _, chunk in rows])
+        # decided profile-to-completion jobs just keep building
+        live = [job for job, _ in rows if job.decision is None]
+        if not live:
+            return []
+        decisions = []
+        for job, d in zip(live, observe_fleet(
+                [(job.controller, job.builder) for job in live])):
+            if d is not None:
+                self._decide(job, d)
                 self._repack()
                 self._sync_store()
+                decisions.append(d)
         return decisions
 
     def finalize(self) -> FleetResult:
@@ -626,19 +572,10 @@ class FleetCapController:
         decision map rather than classified from an empty trace."""
         pending = [j for j in self.jobs.values()
                    if j.decision is None and j.builder.n_ingested > 0]
-        batched = [j for j in pending
-                   if self.engine is not None
-                   and getattr(j.builder, "engine", None) is self.engine]
-        # engine-backed stragglers classify in one batched sweep; decisions
-        # still adopt in admission order so journal replay stays verbatim
-        pre = dict(zip(
-            (j.job_id for j in batched),
-            finalize_fleet([(j.controller, j.builder) for j in batched]))) \
-            if batched else {}
-        for job in pending:
-            decision = pre.get(job.job_id)
-            if decision is None:
-                decision = job.controller.finalize(job.builder)
+        # one batched sweep; decisions still adopt in admission order so
+        # journal replay stays verbatim
+        for job, decision in zip(pending, finalize_fleet(
+                [(j.controller, j.builder) for j in pending])):
             self._decide(job, decision)
         if pending or not self.repacks:
             self._repack()
@@ -680,10 +617,10 @@ class FleetCapController:
         self._sync_store()
 
     def run(self, mux: FleetTelemetryMux) -> FleetResult:
-        """Pump the multiplexed feed to completion: every mux tick advances
-        all simultaneous jobs in one columnar pass, each early cap re-packs
-        the fleet (per the ``repack`` cadence), stragglers decide at stream
-        end.  Outcomes are byte-identical to the per-chunk drain."""
+        """Pump the multiplexed feed to completion: every mux tick goes
+        through ``ingest_tick``, each early cap re-packs the fleet, and the
+        jobs still undecided at stream end decide in ``finalize``.
+        Outcomes are byte-identical to feeding the chunks one at a time."""
         for batch in mux.ticks():
             self.ingest_tick(batch)
         return self.finalize()
@@ -700,7 +637,7 @@ class FleetCapController:
         with obs.span("retire"):
             self._journal(kinds.RETIRE, job_id=job_id)
             job = self.jobs.pop(job_id)
-            self._drop_builder(job.builder)
+            job.builder.release()
             if job.plan is not None:
                 self._unpack(job.plan)
                 self._repack()
@@ -818,16 +755,15 @@ class FleetCapController:
                              f"inventory=...)")
         return self.inventory
 
-    def _auto_degrade(self) -> None:
-        """Degrade-and-drain devices the straggler adapter flags (only
-        meaningful with an inventory; flagged devices without one are left
-        to the caller via ``straggler_adapter.degraded()``)."""
-        if self.inventory is None:
-            return
-        for device_id in self.straggler_adapter.degraded():
-            if device_id in self.inventory \
-                    and self.inventory.health(device_id) == HEALTHY:
-                self.degrade_device(device_id)
+    def _flagged_devices(self) -> list[str]:
+        """Healthy devices the straggler adapter flags, due for
+        degrade-and-drain (none without an inventory: flagged devices are
+        then left to the caller via ``straggler_adapter.degraded()``)."""
+        inv = self.inventory
+        if inv is None:
+            return []
+        return [d for d in self.straggler_adapter.degraded()
+                if d in inv and inv.health(d) == HEALTHY]
 
     def _drain_device(self, device_id: str, cause: FleetEvent,
                       decided_only: bool = False) -> list[FleetEvent]:
@@ -1041,10 +977,11 @@ class FleetCapController:
     def _repack(self) -> None:
         """Record the packing of every decided job into the budget.
 
-        Incremental mode appends an O(1) lazy marker — the packer already
-        tracks every plan mutation, so the ``ScheduleResult`` (byte-
-        identical to a full ``pack()``) materializes only when the entry is
-        actually read.  Full mode runs the reference O(n log n) sweep."""
+        The incremental packer already tracks every plan mutation, so this
+        appends an O(1) lazy marker whose ``ScheduleResult`` (byte-identical
+        to a full ``pack()``) materializes only when the entry is read.
+        After the packer has been given up (``_set_plan``), each re-pack is
+        the full O(n log n) sweep."""
         t0 = perf_counter()
         pk = self._packer
         if pk is not None:

@@ -302,69 +302,46 @@ def _replica_key(ctl, builder):
 
 def observe_fleet(pairs) -> list:
     """Batched ``OnlineCapController.observe`` across many ``(controller,
-    builder)`` pairs (one per fleet job, sharing a classifier): the cheap
-    per-job gates run in pair order, then every gate-passing snapshot goes
-    through ONE ``classify_with_margin_batch`` sweep — with one
-    representative per replica group (see ``_replica_key``) standing in for
-    all its identical siblings.  Returns the per-pair ``CapDecision |
-    None`` list; each decision is bit-identical to what that pair's
-    ``observe`` call would have produced."""
+    builder)`` pairs (one per fleet job, sharing a classifier), whose
+    builders are slots of one ``BatchProfileEngine``: the cheap per-job
+    gates run columnar, then every gate-passing snapshot goes through ONE
+    ``classify_with_margin_batch`` sweep — with one representative per
+    replica group (see ``_replica_key``) standing in for all its identical
+    siblings.  Returns the per-pair ``CapDecision | None`` list; each
+    decision is bit-identical to what that pair's ``observe`` call would
+    have produced."""
     with obs.span("classify"):
         return _observe_fleet(pairs)
 
 
 def _observe_fleet(pairs) -> list:
     out = [None] * len(pairs)
-    # engine-backed slot builders gate and snapshot columnar: one stacked
-    # spike-count row-sum and one snapshot_batch per engine, instead of a
-    # histogram sum + memo prefill per job
-    snap: dict[int, object] = {}
-    gated: set[int] = set()
+    if not pairs:
+        return out
+    eng = pairs[0][1].engine
+    slots = [builder.slot for _, builder in pairs]
     replicas: dict[int, list[int]] = {}
-    by_engine: dict[int, list[int]] = {}
-    engines: dict[int, object] = {}
-    for i, (ctl, builder) in enumerate(pairs):
-        eng = getattr(builder, "engine", None)
-        if eng is not None and not getattr(builder, "_released", True):
-            # identity grouping within this call only: iteration is in
-            # first-appearance order and keys are never serialized
-            by_engine.setdefault(id(eng), []).append(i)  # minoslint: disable=W304
-            engines[id(eng)] = eng  # minoslint: disable=W304
+    # one stacked spike-count row-sum and one snapshot_batch for the whole
+    # sweep, instead of a histogram sum + memo prefill per job
     with obs.span("classify.snapshot"):
-        for key, ids in by_engine.items():
-            eng = engines[key]
-            counts = eng.spike_count_batch([pairs[i][1].slot for i in ids])
-            passing_ids = [
-                i for i, cnt in zip(ids, counts.tolist())
-                if cnt >= pairs[i][0].min_spike_samples
-                and pairs[i][1].fraction >= pairs[i][0].min_fraction]
-            gated.update(ids)
-            reps: list[int] = []
-            first: dict[tuple, int] = {}
-            for i in passing_ids:
-                r = first.setdefault(_replica_key(*pairs[i]), i)
-                if r == i:
-                    reps.append(i)
-                else:
-                    replicas.setdefault(r, []).append(i)
-            snap.update(zip(reps, eng.snapshot_batch(
-                [pairs[i][1].slot for i in reps])))
-    passing = []                 # (i, controller, builder, profile)
-    for i, (ctl, builder) in enumerate(pairs):
-        if i in snap:
-            profile = snap[i]
-        elif i in gated:
-            continue             # batched gates said the evidence is thin
-            # (replica siblings ride on their representative instead)
-        else:
-            if builder.spike_count() < ctl.min_spike_samples:
+        counts = eng.spike_count_batch(slots)
+        reps: list[int] = []
+        first: dict[tuple, int] = {}
+        for i, cnt in enumerate(counts.tolist()):
+            ctl, builder = pairs[i]
+            if cnt < ctl.min_spike_samples \
+                    or builder.fraction < ctl.min_fraction:
                 continue
-            if builder.fraction < ctl.min_fraction:
-                continue
-            profile = builder.snapshot()
-        if len(profile.power_trace) == 0:
-            continue
-        passing.append((i, ctl, builder, profile))
+            r = first.setdefault(_replica_key(ctl, builder), i)
+            if r == i:
+                reps.append(i)
+            else:
+                replicas.setdefault(r, []).append(i)
+        snaps = eng.snapshot_batch([slots[i] for i in reps])
+    # (i, controller, builder, profile); replica siblings ride on their
+    # representative
+    passing = [(i, *pairs[i], profile) for i, profile in zip(reps, snaps)
+               if len(profile.power_trace)]
     decided = 0
     with obs.span("classify.sweep"):
         for group in _grouped(passing):
@@ -388,42 +365,29 @@ def _observe_fleet(pairs) -> list:
 
 
 def finalize_fleet(pairs) -> list:
-    """Batched ``OnlineCapController.finalize``: flush every builder, then
-    classify all completed profiles in one sweep per shared classifier.
-    Returns the per-pair ``CapDecision`` list, in pair order."""
-    # engine-backed slot builders flush through finalize_batch (stacked memo
-    # prefill); plain builders finalize one at a time
-    profs: dict[int, object] = {}
-    by_engine: dict[int, list[int]] = {}
-    engines: dict[int, object] = {}
-    for i, (ctl, builder) in enumerate(pairs):
-        eng = getattr(builder, "engine", None)
-        if eng is not None and not getattr(builder, "_released", True):
-            # identity grouping within this call only: iteration is in
-            # first-appearance order and keys are never serialized
-            by_engine.setdefault(id(eng), []).append(i)  # minoslint: disable=W304
-            engines[id(eng)] = eng  # minoslint: disable=W304
-    for key, ids in by_engine.items():
-        profs.update(zip(ids, engines[key].finalize_batch(
-            [pairs[i][1].slot for i in ids])))
-    entries = [(i, ctl, builder,
-                profs[i] if i in profs else builder.finalize())
-               for i, (ctl, builder) in enumerate(pairs)]
+    """Batched ``OnlineCapController.finalize`` over ``(controller,
+    builder)`` pairs whose builders are slots of one ``BatchProfileEngine``:
+    flush every slot through one ``finalize_batch``, then classify all
+    completed profiles in one sweep per shared classifier.  Returns the
+    per-pair ``CapDecision`` list, in pair order."""
     out = [None] * len(pairs)
-    # replica dedup (see _replica_key): every engine slot still flushed
-    # above — only the classification is shared.  Each sibling's decision
-    # is built from its OWN (bit-identical) profile and builder.
+    if not pairs:
+        return out
+    profs = pairs[0][1].engine.finalize_batch(
+        [builder.slot for _, builder in pairs])
+    # replica dedup (see _replica_key): every slot still flushed above —
+    # only the classification is shared.  Each sibling's decision is built
+    # from its OWN (bit-identical) profile and builder.
     replicas: dict[int, list] = {}
     first: dict[tuple, int] = {}
     lead = []
-    for e in entries:
-        i, ctl, builder, _ = e
-        if i in profs:
-            r = first.setdefault(_replica_key(ctl, builder), i)
-            if r != i:
-                replicas.setdefault(r, []).append(e)
-                continue
-        lead.append(e)
+    for i, ((ctl, builder), profile) in enumerate(zip(pairs, profs)):
+        e = (i, ctl, builder, profile)
+        r = first.setdefault(_replica_key(ctl, builder), i)
+        if r != i:
+            replicas.setdefault(r, []).append(e)
+        else:
+            lead.append(e)
     for group in _grouped(lead):
         results = classify_with_margin_batch(
             [p for _, _, _, p in group], group[0][1].clf,

@@ -3,16 +3,21 @@ telemetry mux, device-portable classification, and the pinned invariance —
 on a homogeneous zero-variability fleet, ``FleetCapController`` decisions
 are byte-identical to the single-job ``OnlineCapController`` path."""
 import dataclasses
+from contextlib import nullcontext
 
 import numpy as np
 import pytest
 
 from repro.analysis.hardware import CHIP_MODELS, V5E
 from repro.core.algorithm1 import select_optimal_freq
-from repro.fleet import (DeviceInstance, DeviceInventory, FleetCapController,
-                         FleetTelemetryMux, VariabilityModel)
+from repro.fleet import (DEGRADED, DeviceInstance, DeviceInventory,
+                         FleetCapController, FleetChunk, FleetTelemetryMux,
+                         VariabilityModel)
+from repro.ft import FleetStragglerAdapter, StragglerMonitor
 from repro.pipeline import (OnlineCapController, ReferenceLibrary,
                             stream_profile_once, stream_profile_workload)
+from repro.sched import PowerAwareScheduler
+from repro.store import Encoded
 from repro.telemetry import TPUPowerModel, simulate, stream_telemetry
 from repro.telemetry.kernel_stream import (micro_gemm, micro_idle_burst,
                                            micro_spmv_compute,
@@ -390,48 +395,191 @@ def test_mux_ticks_batches_equal_timestamps_in_chunk_order():
 
 
 def test_fleet_batched_engine_matches_perjob_engine(micro_library):
-    """Fleet-level pin: engine='batched' through the tick path produces the
-    byte-identical decisions and final packing as engine='perjob' through
-    the per-chunk path, and repack='tick' converges to the same packing."""
+    """Fleet-level pin: the controller's tick path lands the decisions, the
+    final packing and the dropped-chunk count of the plain per-job
+    references — ``OnlineCapController.run`` per job (one standalone
+    ``ProfileBuilder`` each) at its device's effective TDP, then one
+    ``PowerAwareScheduler.pack`` over the plans."""
     jobs = [(micro_gemm, 0), (micro_spmv_memory, 1), (micro_spmv_compute, 2),
             (micro_idle_burst, 3)]
+    budget = 5000.0
+    inv = DeviceInventory.generate(4, VariabilityModel(), seed=7)
+    fleet = FleetCapController(micro_library, budget_w=budget, **GATES)
+    mux = FleetTelemetryMux()
+    streams = []
+    for (fn, seed), dev in zip(jobs, inv):
+        meta, chunks = _job_stream(fn, seed=seed, device_id=dev.device_id)
+        chunks = list(chunks)
+        job_id = fleet.admit(dev, meta, chips=4)
+        mux.add_job(job_id, meta, chunks)
+        streams.append((job_id, dev, meta, chunks))
+    got = fleet.run(mux)
 
-    def run(engine, repack, per_chunk=False):
-        inv = DeviceInventory.generate(4, VariabilityModel(), seed=7)
-        fleet = FleetCapController(micro_library, budget_w=5000.0,
-                                   engine=engine, repack=repack, **GATES)
-        mux = FleetTelemetryMux()
-        for (fn, seed), dev in zip(jobs, inv):
-            meta, chunks = _job_stream(fn, seed=seed,
-                                       device_id=dev.device_id)
-            mux.add_job(fleet.admit(dev, meta, chips=4), meta, chunks)
-        if per_chunk:
-            for fc in mux:
-                fleet.ingest(fc)
-            return fleet.finalize()
-        return fleet.run(mux)
-
-    ref = run("perjob", "decision", per_chunk=True)
-    got = run("batched", "decision")
-    assert set(got.decisions) == set(ref.decisions)
-    for job_id, expect in ref.decisions.items():
+    clf = micro_library.classifier()
+    sched = PowerAwareScheduler(clf, tdp_w=0.0, quantile="p99")
+    decisions, plans, dropped = {}, [], 0
+    for job_id, dev, meta, chunks in streams:
+        ctl = OnlineCapController(clf, device_id=dev.device_id, **GATES)
+        feed = iter(chunks)
+        d = decisions[job_id] = ctl.run(meta, feed, dev.effective_tdp_w)
+        dropped += sum(1 for _ in feed)     # left unread by the decision
+        plans.append(sched.plan_from_selection(d.selection, 4, dev,
+                                               job_id=job_id))
+    assert list(got.decisions) == list(decisions)
+    for job_id, expect in decisions.items():
         assert got.decisions[job_id] == expect
-    assert got.repacks == ref.repacks
-    assert got.schedule == ref.schedule
-    assert got.chunks_dropped == ref.chunks_dropped
-    # tick-cadence repacking: fewer scheduler calls, same final packing
-    coarse = run("batched", "tick")
-    assert coarse.decisions == ref.decisions
-    assert coarse.schedule == ref.schedule
-    assert coarse.repacks <= ref.repacks
+    assert got.early_decisions > 0 and dropped > 0
+    assert got.schedule == sched.pack(plans, budget)
+    assert got.chunks_dropped == dropped
+
+
+class _RecordingJournal:
+    """Stands in for a session store: keeps every record, in order."""
+
+    def __init__(self):
+        self.records = []
+
+    def record(self, kind, **data):
+        self.records.append((kind, {k: v.value if isinstance(v, Encoded)
+                                    else v for k, v in data.items()}))
+
+    def batch(self):
+        return nullcontext()
+
+    def flush_snapshot(self):
+        pass
+
+
+def _tick_vs_per_chunk(library, admit_and_feed, fleet_kw):
+    """Drive the same fleet twice: every chunk of ``feed`` in one
+    ``ingest_tick``, then one ``ingest`` per chunk.  Returns both
+    ``(fleet, tick decisions, result, journal records, engine calls)``."""
+    out = []
+    for tick in (True, False):
+        journal = _RecordingJournal()
+        fleet = FleetCapController(library, journal=journal,
+                                   **{**GATES, **fleet_kw()})
+        feed = admit_and_feed(fleet)
+        calls = []
+        ingest_batch = fleet.engine.ingest_batch
+
+        def counted(slots, chunks):
+            calls.append(len(slots))
+            return ingest_batch(slots, chunks)
+        fleet.engine.ingest_batch = counted
+        if tick:
+            decisions = fleet.ingest_tick(feed)
+        else:
+            decisions = [d for d in map(fleet.ingest, feed) if d is not None]
+        mid = (list(fleet.events),
+               [(r.planned_power_w, r.nameplate_power_w, r.budget_w)
+                for r in fleet.repacks], fleet.repacks[-1])
+        out.append((fleet, decisions, mid, fleet.finalize(),
+                    journal.records, calls))
+    return out
+
+
+def _assert_same_outcome(tick, per_chunk):
+    fleet_t, dec_t, mid_t, res_t, rec_t, _ = tick
+    fleet_c, dec_c, mid_c, res_c, rec_c, _ = per_chunk
+    assert dec_t == dec_c
+    assert mid_t == mid_c                  # events, every re-pack, the last
+    assert res_t.decisions == res_c.decisions
+    assert list(res_t.decisions) == list(res_c.decisions)
+    assert res_t.schedule == res_c.schedule
+    assert res_t.repacks == res_c.repacks
+    assert res_t.chunks_dropped == res_c.chunks_dropped
+    assert res_t.events == res_c.events
+    assert rec_t == rec_c                  # journal records, in order
+    for job_id, job in fleet_t.jobs.items():
+        assert job.builder.n_ingested \
+            == fleet_c.jobs[job_id].builder.n_ingested
+
+
+@pytest.mark.parametrize("chunk_samples", [25, 60])
+def test_straggler_tick_equals_per_chunk_feed(micro_library, chunk_samples):
+    """One ``ingest_tick`` over a whole interleaved feed, with a straggler
+    adapter whose degrade fires mid-batch, lands what feeding the chunks
+    one at a time through ``ingest`` lands — and still advances the engine
+    many rows per call, not one chunk at a time.  With 60-sample chunks
+    the slow device's job decides in the run that the degrade cuts short,
+    so the degrade drains it only if that run lands first; with 25-sample
+    chunks the degrade comes first and the job migrates the moment it
+    decides."""
+    def inventory():
+        return DeviceInventory.generate(3, VariabilityModel.none(), seed=0)
+
+    def admit_and_feed(fleet):
+        inv = fleet.inventory
+        streams = []
+        for i, fn in enumerate([micro_gemm, micro_spmv_memory,
+                                micro_stencil]):
+            meta, chunks = stream_telemetry(
+                fn(), 1.0, inv[i].power_model(), seed=i,
+                target_duration=0.5, chunk_samples=chunk_samples,
+                device_id=inv[i].device_id)
+            streams.append((fleet.admit(inv[i], meta, chips=2),
+                            list(chunks)))
+        # device 2 polls at a tenth of the others' cadence
+        rounds = min(len(c) for _, c in streams)
+        return [FleetChunk(job_id, inv[i].device_id,
+                           r * (0.5 if i == 2 else 0.05), chunks[r])
+                for r in range(rounds)
+                for i, (job_id, chunks) in enumerate(streams)]
+
+    def fleet_kw():
+        return dict(budget_w=1e9, inventory=inventory(),
+                    straggler_adapter=FleetStragglerAdapter(
+                        StragglerMonitor(min_samples=4, k=4.0)),
+                    min_confidence=0.0, min_spike_samples=10)
+
+    tick, per_chunk = _tick_vs_per_chunk(micro_library, admit_and_feed,
+                                         fleet_kw)
+    _assert_same_outcome(tick, per_chunk)
+    fleet, decisions, (events, _, _), _, _, calls = tick
+    slow = fleet.inventory[2].device_id
+    assert [(e.kind, e.device_id) for e in events] == [
+        ("degrade", slow), ("migrate", slow)]
+    assert len(decisions) == 3
+    assert fleet.inventory.health(slow) == DEGRADED
+    assert max(calls) > 1 and len(calls) < len(per_chunk[-1])
+
+
+def test_tick_with_repeated_jobs_equals_per_chunk_feed(micro_library):
+    """A batch that holds several chunks of the same jobs (here a whole
+    feed, the last of whose jobs profiles to completion) cuts into runs of
+    distinct jobs and lands what the per-chunk feed lands.  The first job
+    decides early, so its next chunk is the one that cuts the run in
+    which it decides: that chunk is dropped only if the run lands before
+    the chunk is filtered."""
+    def admit_and_feed(fleet):
+        inv = DeviceInventory.generate(3, VariabilityModel(), seed=3)
+        mux = FleetTelemetryMux()
+        for i, (fn, dev) in enumerate(zip(
+                [micro_gemm, micro_spmv_memory, micro_idle_burst], inv)):
+            meta, chunks = _job_stream(fn, seed=i, device_id=dev.device_id)
+            mux.add_job(fleet.admit(dev, meta, chips=4,
+                                    profile_to_completion=i == 2),
+                        meta, chunks)
+        return list(mux)
+
+    tick, per_chunk = _tick_vs_per_chunk(
+        micro_library, admit_and_feed, lambda: dict(budget_w=5000.0))
+    _assert_same_outcome(tick, per_chunk)
+    fleet, decisions, _, result, _, calls = tick
+    assert decisions and result.chunks_dropped > 0
+    assert max(calls) > 1 and len(calls) < len(per_chunk[-1])
+    ptc = list(fleet.jobs.values())[-1]
+    assert ptc.builder.n_ingested == ptc.builder.meta.n_samples
 
 
 @pytest.mark.parametrize("engine", ["batched", "perjob"])
 def test_engine_profiles_carry_their_p90(micro_library, monkeypatch, engine):
     """An engine-minted profile's p90 comes from its slot's order
     statistics: the sweep (``observe_fleet``), ``finalize_job`` and
-    ``finalize_fleet`` take no percentile over its trace.  A per-job
-    ``ProfileBuilder``'s profile still takes ``np.percentile``."""
+    ``finalize_fleet`` take no percentile over its trace.  The same streams
+    through standalone ``ProfileBuilder``s (``OnlineCapController.run``)
+    still take ``np.percentile``."""
     from repro.core import spikes
     from repro.pipeline import online
 
@@ -453,14 +601,22 @@ def test_engine_profiles_carry_their_p90(micro_library, monkeypatch, engine):
     inv = DeviceInventory.generate(4, VariabilityModel(), seed=7)
     # no early decision clears the gate: every tick sweeps every job, and
     # every decision comes from a stream end
-    fleet = FleetCapController(micro_library, budget_w=5000.0, engine=engine,
-                               **{**GATES, "min_confidence": 1.01})
+    gates = {**GATES, "min_confidence": 1.01}
+    streams = [(dev, *_job_stream(fn, seed=i, device_id=dev.device_id))
+               for i, (fn, dev) in enumerate(zip(
+                   (micro_gemm, micro_spmv_memory, micro_spmv_compute,
+                    micro_idle_burst), inv))]
+    if engine == "perjob":
+        for dev, meta, chunks in streams:
+            d = OnlineCapController(micro_library, **gates).run(
+                meta, chunks, dev.effective_tdp_w)
+            assert not d.early
+        assert "percentile" in calls
+        return
+    fleet = FleetCapController(micro_library, budget_w=5000.0, **gates)
     mux = FleetTelemetryMux()
     jobs = []
-    for fn, dev in zip((micro_gemm, micro_spmv_memory, micro_spmv_compute,
-                        micro_idle_burst), inv):
-        meta, chunks = _job_stream(fn, seed=len(jobs),
-                                   device_id=dev.device_id)
+    for dev, meta, chunks in streams:
         jobs.append(fleet.admit(dev, meta, chips=4))
         mux.add_job(jobs[-1], meta, chunks)
     swept = 0
@@ -471,7 +627,4 @@ def test_engine_profiles_carry_their_p90(micro_library, monkeypatch, engine):
             fleet.finalize_job(jobs[0])          # one stream end alone
     result = fleet.finalize()                    # the rest in one sweep
     assert swept > 3 and len(result.decisions) == len(jobs)
-    if engine == "batched":
-        assert calls == []
-    else:
-        assert "percentile" in calls
+    assert calls == []
