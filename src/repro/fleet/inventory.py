@@ -93,7 +93,10 @@ _HEALTH_STATES = (HEALTHY, DEGRADED, FAILED)
 
 class DeviceInventory:
     """Ordered collection of ``DeviceInstance``s with deterministic
-    generation, simple lookup/grouping, and per-device health state.
+    generation, grouping, and per-device health state.  The device list is
+    fixed at construction; lookups by id are O(1) through an id-keyed index
+    built once in ``__init__`` (a fault path asks for every live job's
+    device, so a scan per lookup would cost devices x jobs).
 
     Health is inventory-level (the instances stay frozen value objects):
     ``mark_failed``/``mark_degraded``/``restore`` move a device between
@@ -104,10 +107,11 @@ class DeviceInventory:
 
     def __init__(self, devices=()):
         self._devices: list[DeviceInstance] = list(devices)
-        ids = [d.device_id for d in self._devices]
-        if len(set(ids)) != len(ids):
+        self._by_id: dict[str, DeviceInstance] = {
+            d.device_id: d for d in self._devices}
+        if len(self._by_id) != len(self._devices):
             raise ValueError("duplicate device_id in inventory")
-        self._health: dict[str, str] = {i: HEALTHY for i in ids}
+        self._health: dict[str, str] = dict.fromkeys(self._by_id, HEALTHY)
 
     @classmethod
     def generate(cls, counts: dict[str, int] | int,
@@ -149,10 +153,7 @@ class DeviceInventory:
         return key in self._health
 
     def get(self, device_id: str) -> DeviceInstance:
-        for d in self._devices:
-            if d.device_id == device_id:
-                return d
-        raise KeyError(device_id)
+        return self._by_id[device_id]        # KeyError on unknown device
 
     def by_model(self, model: str) -> list[DeviceInstance]:
         return [d for d in self._devices if d.model == model]
@@ -172,7 +173,8 @@ class DeviceInventory:
 
     # -- health ----------------------------------------------------------
     def _set_health(self, device_id: str, state: str) -> None:
-        self.get(device_id)                  # KeyError on unknown device
+        if device_id not in self._health:
+            raise KeyError(device_id)
         self._health[device_id] = state
 
     def mark_failed(self, device_id: str) -> None:
@@ -191,8 +193,7 @@ class DeviceInventory:
         self._set_health(device_id, HEALTHY)
 
     def health(self, device_id: str) -> str:
-        self.get(device_id)
-        return self._health[device_id]
+        return self._health[device_id]       # KeyError on unknown device
 
     def is_healthy(self, device_id: str) -> bool:
         return self.health(device_id) == HEALTHY

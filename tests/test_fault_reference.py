@@ -10,6 +10,9 @@ that strand jobs and restores that bring them back:
     TDP (same cap and selection);
   * the packing equals ``PowerAwareScheduler.pack`` over the same plans;
   * no call classifies (``count_classifier_calls``)."""
+from contextlib import nullcontext
+
+import numpy as np
 import pytest
 
 from repro.api import (ReferenceLibrary, TPUPowerModel,
@@ -18,6 +21,7 @@ from repro.api import (ReferenceLibrary, TPUPowerModel,
                        stream_profile_workload, stream_telemetry)
 from repro.fleet import (FAILED, HEALTHY, DeviceInventory, FleetCapController,
                          VariabilityModel)
+from repro.store import Encoded
 
 MODEL = TPUPowerModel()
 GATES = dict(min_confidence=0.2, min_fraction=0.1, min_spike_samples=50)
@@ -178,3 +182,94 @@ def test_fail_and_restore_follow_the_reference(library):
     assert all(j.plan is not None for jid, j in fleet.jobs.items()
                if decisions[jid] is not None)
     assert calls["n"] == 0
+
+
+class _RecordingJournal:
+    """Stands in for a session store: keeps every record, in order."""
+
+    def __init__(self):
+        self.records = []
+
+    def record(self, kind, **data):
+        self.records.append((kind, {k: v.value if isinstance(v, Encoded)
+                                    else v for k, v in data.items()}))
+
+    def batch(self):
+        return nullcontext()
+
+    def flush_snapshot(self):
+        pass
+
+
+def _scheduled_faults(library, inv, streams, schedule):
+    """More jobs than devices, two in three decided, under a binding
+    budget; then ``schedule``'s fails and restores.  Returns the fleet
+    and its journal."""
+    journal = _RecordingJournal()
+    fleet = FleetCapController(library, budget_w=1e9, inventory=inv,
+                               journal=journal, **GATES)
+    devices = list(inv)
+    for i, (meta, chunks) in enumerate(streams):
+        jid = fleet.admit(devices[i % len(devices)], meta, chips=1 + i % 3,
+                          job_id=f"j{i}")
+        if i % 3 == 2:
+            fleet.ingest_chunk(jid, chunks[0])
+        else:
+            fleet.finalize_job(jid)
+    fleet.set_budget(0.6 * sum(j.plan.predicted_p90_w * j.plan.chips
+                               for j in fleet.jobs.values()
+                               if j.plan is not None))
+    for kind, dev in schedule:
+        (fleet.fail_device if kind == "fail" else fleet.restore_device)(dev)
+        for job in fleet.jobs.values():
+            if job.plan is not None:
+                assert inv.health(job.device.device_id) == HEALTHY
+    return fleet, journal
+
+
+def test_fault_schedule_is_the_same_over_a_rebuilt_inventory(library):
+    """A seeded fail/restore schedule over a few hundred devices, with more
+    jobs than devices, migrates, strands and re-places the same jobs and
+    journals the same records over an inventory rebuilt from the same
+    instances; no plan is ever bound to a down device."""
+    inv = DeviceInventory.generate(240, VariabilityModel(), seed=11)
+    ids = [d.device_id for d in inv]
+    nominal = TPUPowerModel()
+    streams = []
+    for i in range(300):
+        meta, chunks = stream_telemetry(
+            STREAMS[i % len(STREAMS)](), 1.0, nominal, seed=i % 10,
+            target_duration=0.5, chunk_samples=128)
+        streams.append((meta, list(chunks)))
+    rng = np.random.default_rng(20)
+    down: list[str] = []
+    schedule = []
+    for _ in range(60):                  # random churn, a few down at a time
+        if down and rng.random() < 0.4:
+            dev = down.pop(int(rng.integers(len(down))))
+            schedule.append(("restore", dev))
+        else:
+            dev = str(rng.choice([i for i in ids if i not in down]))
+            down.append(dev)
+            schedule.append(("fail", dev))
+    # every device down, so every job strands; then capacity returns, the
+    # stranded jobs' own device first
+    last = [i for i in ids if i not in down]
+    schedule += [("fail", i) for i in last]
+    schedule += [("restore", last[-1])] + [
+        ("restore", str(i)) for i in rng.choice(
+            [i for i in ids if i != last[-1]], size=11, replace=False)]
+    runs = [_scheduled_faults(library, fresh, streams, schedule)
+            for fresh in (inv, DeviceInventory(list(inv)))]
+    (fleet, journal), (again, journal_again) = runs
+    kinds = {e.kind for e in fleet.events}
+    assert {"migrate", "strand", "restore"} <= kinds
+    assert any(e.detail == "re-placed after restore" for e in fleet.events)
+    assert fleet.events == again.events
+    assert journal.records == journal_again.records
+    assert fleet.device_health() == again.device_health()
+    for jid, job in fleet.jobs.items():
+        other = again.jobs[jid]
+        assert job.device is other.device, jid
+        assert job.plan == other.plan, jid
+    assert fleet.repacks[-1] == again.repacks[-1]

@@ -10,9 +10,9 @@ import pytest
 
 from repro.analysis.hardware import CHIP_MODELS, V5E
 from repro.core.algorithm1 import select_optimal_freq
-from repro.fleet import (DEGRADED, DeviceInstance, DeviceInventory,
-                         FleetCapController, FleetChunk, FleetTelemetryMux,
-                         VariabilityModel)
+from repro.fleet import (DEGRADED, FAILED, HEALTHY, DeviceInstance,
+                         DeviceInventory, FleetCapController, FleetChunk,
+                         FleetTelemetryMux, VariabilityModel)
 from repro.ft import FleetStragglerAdapter, StragglerMonitor
 from repro.pipeline import (OnlineCapController, ReferenceLibrary,
                             stream_profile_once, stream_profile_workload)
@@ -87,6 +87,43 @@ def test_inventory_lookup_and_validation():
     dup = inv[0]
     with pytest.raises(ValueError, match="duplicate device_id"):
         DeviceInventory([dup, dup])
+
+
+@pytest.mark.parametrize("n", [1, 3, 2000])
+def test_inventory_index_answers_as_iteration(n):
+    """Lookups by id answer exactly what iterating the device list does, at
+    any size; health moves keep the views in inventory order, and an
+    unknown id raises ``KeyError`` naming it."""
+    inv = DeviceInventory.generate(n, VariabilityModel(), seed=7)
+    assert len(inv) == n
+    health = inv.device_health
+    for i, d in enumerate(inv):
+        assert inv.get(d.device_id) is d is inv[i]
+        assert d.device_id in inv and d in inv
+        assert inv.health(d.device_id) == health[d.device_id] == HEALTHY
+        assert inv.is_healthy(d.device_id)
+    ids = [d.device_id for d in inv]
+    down = ids[::2]
+    for did in reversed(down):
+        inv.mark_failed(did)
+    assert inv.failed_ids == down
+    assert [d.device_id for d in inv.healthy] == ids[1::2]
+    for did in down:
+        assert inv.health(did) == FAILED and not inv.is_healthy(did)
+    inv.restore(down[-1])
+    assert inv.failed_ids == down[:-1]
+    assert [d.device_id for d in inv.healthy] \
+        == [i for i in ids if i not in down[:-1]]
+    assert list(inv.device_health) == ids
+    assert inv.device_health == {
+        i: FAILED if i in down[:-1] else HEALTHY for i in ids}
+    missing = "tpu-v5e/nope"
+    for op in (inv.get, inv.health, inv.is_healthy, inv.mark_failed,
+               inv.restore):
+        with pytest.raises(KeyError, match=missing):
+            op(missing)
+    assert missing not in inv
+    assert list(inv.device_health) == ids
 
 
 # ---------------------------------------------------------------------------
